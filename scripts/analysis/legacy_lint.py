@@ -43,6 +43,9 @@ ALLOWED_METRIC_LABELS = frozenset((
     # Leopard fragment maintenance state (indexed | quarantined |
     # retired — bounded by the code, not by traffic)
     "state",
+    # the jax platform and device kind a process serves from (one
+    # series per process)
+    "platform", "device_kind",
 ))
 _METRIC_FACTORIES = ("counter", "gauge", "histogram")
 _M001_PREFIX = "spicedb_kubeapi_proxy_tpu"

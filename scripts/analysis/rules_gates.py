@@ -50,8 +50,8 @@ GATED_MODULES = (
     ("utils/workload.py", "KernelIntrospect"),
     ("utils/profiler.py", "Profiler"),
     # multi-chip mesh execution: the sharded kernel module rides the
-    # MeshExecution killswitch (compat.py/distributed.py are pure
-    # resolution/runtime glue with no subsystem state to gate; the
+    # MeshExecution killswitch (distributed.py is pure
+    # runtime glue with no subsystem state to gate; the
     # endpoint checks the gate at mesh construction)
     ("parallel/sharding.py", "MeshExecution"),
     # Leopard materialized group index: the closure planner/builder and

@@ -21,8 +21,9 @@ Cost control (the smoke time box):
 - two worker processes (spawned, jax-safe) split the seed set;
 - `--xla_backend_optimization_level=0` (tiny graphs need fast COMPILE,
   not fast code) via a re-exec before jax initializes;
-- a persistent jax compilation cache under /tmp keyed by HLO, so
-  repeat runs (the common check.sh case) skip XLA entirely;
+- the persistent jax compilation cache every entry point shares
+  (utils/device.configure_compile_cache) keyed by HLO, so repeat runs
+  (the common check.sh case) skip XLA entirely;
 - the smoke case profile (driver.build_case(smoke=True)): bounded
   schema size, short streams, end-state checkpoints.
 
@@ -52,14 +53,11 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-_XLA_CACHE_DIR = os.environ.get("FUZZ_XLA_CACHE",
-                                "/tmp/authz_fuzz_xla_cache")
-
 if os.environ.get("_FUZZ_SMOKE_REEXEC") != "1":
-    # compile-speed flags must be in place before the interpreter (or
-    # any sitecustomize) initializes a jax backend — re-exec with them
-    # the forced host device count gives the `mesh` cells their 2x2
-    # virtual mesh (a no-op for every other cell)
+    # compile-speed flags must be in place before jax initializes its
+    # backend — re-exec with them; the forced host device count gives
+    # the `mesh` cells their 2x2 virtual mesh (a no-op for every other
+    # cell)
     env = dict(os.environ, _FUZZ_SMOKE_REEXEC="1", JAX_PLATFORMS="cpu",
                XLA_FLAGS=(os.environ.get("XLA_FLAGS", "")
                           + " --xla_backend_optimization_level=0"
@@ -70,13 +68,14 @@ ARTIFACT_DIR = os.environ.get("FUZZ_ARTIFACT_DIR", "/tmp/authz_fuzz")
 
 
 def _enable_compile_cache() -> None:
+    from spicedb_kubeapi_proxy_tpu.utils.device import (
+        configure_compile_cache,
+    )
+    configure_compile_cache()
     import jax
-    try:
-        jax.config.update("jax_compilation_cache_dir", _XLA_CACHE_DIR)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    except Exception:
-        pass  # older jax: cache is an optimization, not a requirement
+    # the smoke's graphs are tiny: cache every compile, however fast
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
 
 def cell_for(seed: int) -> tuple:

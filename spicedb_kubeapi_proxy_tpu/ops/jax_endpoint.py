@@ -50,6 +50,7 @@ import threading
 import time
 from typing import Iterable, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -430,8 +431,7 @@ def _start_readback(dev, batch_id, bucket: int, sweep_bytes: int,
                 "kernel.device", {"kind": kind, "bucket": bucket,
                                   "workload": comp},
                 t_ready - t0)
-            if hasattr(dev, "copy_to_host_async"):
-                dev.copy_to_host_async()
+            dev.copy_to_host_async()
             host = np.asarray(dev)
             timeline.record("transfer", "device", t_ready,
                             batch=batch_id, bucket=bucket,
@@ -2173,7 +2173,13 @@ class JaxEndpoint(PermissionsEndpoint):
             for attempt in range(self._BG_REPLAY_ATTEMPTS):
                 st = self._build_candidate()
                 if self.prewarm_rebuilds:
-                    self._prewarm_graph(st._graph)
+                    try:
+                        self._prewarm_graph(st._graph)
+                    except Exception:
+                        # the generation installs anyway and compiles
+                        # on first use, as it would without prewarm
+                        _log.exception("prewarm of a rebuilt generation "
+                                       "failed")
                 with self._lock:
                     if self._gen_epoch != self._bg_epoch:
                         # a sync rebuild (force_rebuild / store reset)
@@ -2224,17 +2230,15 @@ class JaxEndpoint(PermissionsEndpoint):
         path AND candidate generations BEFORE they are swapped in
         (off-lock, graph not yet visible), so first requests recompile
         nothing.  Returns the number of entry points warmed (0 when
-        the graph has no prewarm or it failed — serving unaffected)."""
+        the graph has no prewarm); a compile that fails raises, so a
+        program the device refuses stops warm start instead of
+        surfacing at the first request."""
         fn = getattr(graph, "prewarm", None)
         if fn is None:
             return 0
-        try:
-            return fn(lanes=self._PREWARM_LANES,
-                      slot_ranges=self._prewarm_slot_ranges(graph),
-                      pipelined=_pipeline_on())
-        except Exception:
-            _log.exception("prewarm failed (serving unaffected)")
-            return 0
+        return fn(lanes=self._PREWARM_LANES,
+                  slot_ranges=self._prewarm_slot_ranges(graph),
+                  pipelined=_pipeline_on())
 
     def _prewarm_slot_ranges(self, graph) -> list:
         slot_ranges = []
@@ -2934,7 +2938,7 @@ class JaxEndpoint(PermissionsEndpoint):
                     # contiguous per word column.
                     packed_T = graph.run_lookup_packed(rng[0], rng[1], q_arr,
                                                        snap=snap).T
-                    if hasattr(packed_T, "copy_to_host_async"):
+                    if isinstance(packed_T, jax.Array):
                         packed_T.copy_to_host_async()
                     ctx["packed_T"] = packed_T
                 else:
@@ -3007,7 +3011,7 @@ class JaxEndpoint(PermissionsEndpoint):
             with tracing.kernel_span("kernel.transfer",
                                      kind="lookup_batch") as a:
                 a["batch_id"] = ctx.get("batch_id")
-                if not hasattr(ctx["packed_T"], "copy_to_host_async"):
+                if not isinstance(ctx["packed_T"], jax.Array):
                     # the pending result is already a host array (the
                     # packed kernels sync at capture): the block here is
                     # the word-transpose copy, not a device transfer —

@@ -7,8 +7,7 @@ remote device-backed permission server, and (b) — TPU-natively — one
 `jax://` endpoint spanning a MULTI-HOST device mesh: every proxy process
 joins a `jax.distributed` cluster, `jax.devices()` becomes the global
 device set, and the same 2D (data x graph) `shard_map` program from
-parallel/sharding.py (resolved for the running jax version by
-parallel/compat.shard_map) runs with the graph axis striped across hosts
+parallel/sharding.py runs with the graph axis striped across hosts
 (XLA routes per-iteration all_gathers over ICI within a slice and DCN
 across slices — SURVEY.md §5 communication-backend note).
 
@@ -37,11 +36,7 @@ def _runtime_initialized() -> bool:
     or not this module did it)."""
     import jax
 
-    try:
-        return bool(jax.distributed.is_initialized())
-    except AttributeError:  # older jax: fall back to the client handle
-        from jax._src import distributed as _dist
-        return _dist.global_state.client is not None
+    return bool(jax.distributed.is_initialized())
 
 
 def init_from_env(coordinator: Optional[str] = None,

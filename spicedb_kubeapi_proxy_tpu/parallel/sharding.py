@@ -35,7 +35,6 @@ from ..ops.spmv import (MAX_ITERATIONS, bucket, make_evaluate,
                         pad_edges, pad_scatter)
 from ..utils import devtel, workload
 from ..utils.failpoints import fail_point
-from .compat import shard_map
 
 
 def make_mesh(devices=None, data: Optional[int] = None,
@@ -60,6 +59,13 @@ def make_mesh(devices=None, data: Optional[int] = None,
     return Mesh(arr, axis_names=("data", "graph"))
 
 
+def replicate(mesh: Mesh, a) -> jax.Array:
+    """A host array placed on every device of `mesh`: operands a sharded
+    program reads whole (gather indices, scatter rows) go there directly
+    instead of landing on the default device first."""
+    return jax.device_put(np.asarray(a), NamedSharding(mesh, P()))
+
+
 def make_sharded_evaluate(prog: GraphProgram, mesh: Mesh, num_iters: int):
     """Build fn(q_idx, edge_src, edge_dst) -> x_final [N, B] where q_idx is
     sharded over `data` and the edge arrays over `graph`.  The state vector
@@ -70,7 +76,7 @@ def make_sharded_evaluate(prog: GraphProgram, mesh: Mesh, num_iters: int):
         changed_reduce=lambda c: jax.lax.pmax(
             c.astype(jnp.int32), ("data", "graph")) > 0,
     )
-    return shard_map(
+    return jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P("data"), P("graph"), P("graph")),
         out_specs=P(None, "data"),
@@ -143,7 +149,8 @@ class ShardedKernel:
         gc = np.zeros(g, np.int32)
         gi[: len(gather_idx)] = gather_idx
         gc[: len(gather_col)] = gather_col
-        out = np.asarray(self._checks(q, jnp.asarray(gi), jnp.asarray(gc),
+        out = np.asarray(self._checks(q, replicate(self.mesh, gi),
+                                      replicate(self.mesh, gc),
                                       edge_src, edge_dst))
         return out[: len(gather_idx)]
 
@@ -421,8 +428,9 @@ class ShardedEllKernel:
             # scatter's output sharding propagation decided
             return jax.device_put(out, self._row_spec)
 
-        return self._run_collective(scatter, arr, jnp.asarray(rows),
-                                    jnp.asarray(vals))
+        return self._run_collective(scatter, arr,
+                                    replicate(self.mesh, rows),
+                                    replicate(self.mesh, vals))
 
     def update_main_rows(self, rows: np.ndarray, vals: np.ndarray) -> None:
         self.idx_main = self._scatter_rows(self.idx_main, rows,
@@ -624,8 +632,8 @@ class ShardedEllKernel:
             out_specs = (state_sp, P(None)) if introspect else state_sp
         else:
             out_specs = state_sp
-        return shard_map(shard_fn, mesh=self.mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
+        return jax.shard_map(shard_fn, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
     def _fns(self) -> tuple:
         fns = self._jits.get("serial")
@@ -758,7 +766,9 @@ class ShardedEllKernel:
             return a
         rows = self.n_pad + self.a_pad
         shape = (rows, n_words, 2) if self.planes else (rows, n_words)
-        a = jax.device_put(jnp.zeros(shape, jnp.uint32), self._state_spec)
+        # zeros made in place on every shard, not on one device first
+        a = jax.jit(lambda: jnp.zeros(shape, jnp.uint32),
+                    out_shardings=self._state_spec)()
         devtel.LEDGER.register("state_arena", int(a.nbytes),
                                generation=self.devtel_generation,
                                name=f"arena:{n_words}")
@@ -794,7 +804,8 @@ class ShardedEllKernel:
         run_checks, _, intro = self._pipe_fns()
         state = self.take_arena(n_words)
         q = jax.device_put(np.asarray(q_idx, np.int32), self._q_spec)
-        args = [q, jnp.asarray(gather_idx), jnp.asarray(gather_col),
+        args = [q, replicate(self.mesh, np.asarray(gather_idx, np.int32)),
+                replicate(self.mesh, np.asarray(gather_col, np.int32)),
                 state, idx_main, idx_aux]
         if self.planes:
             args.append(idx_cav)
@@ -885,8 +896,9 @@ class ShardedEllKernel:
         gi[: len(gather_idx)] = gather_idx
         gcol[: len(gather_col)] = gather_col
         out = np.asarray(self._run_collective(
-            run_checks, q, jnp.asarray(gi), jnp.asarray(gcol // 32),
-            jnp.asarray((gcol % 32).astype(np.uint32)),
+            run_checks, q, replicate(self.mesh, gi),
+            replicate(self.mesh, (gcol // 32).astype(np.int32)),
+            replicate(self.mesh, (gcol % 32).astype(np.uint32)),
             *self._table_args(tables)))
         if self.planes:
             return out[: len(gather_idx)].astype(np.int8)

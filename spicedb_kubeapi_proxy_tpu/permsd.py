@@ -93,9 +93,10 @@ async def run(args, ready_cb=None) -> None:
 
 
 def main(argv: Optional[list] = None) -> int:
-    from .cli import _normalize_argv, _sync_jax_platforms
+    from .cli import _normalize_argv
+    from .utils.device import configure_compile_cache
 
-    _sync_jax_platforms()
+    configure_compile_cache()
     args = build_parser().parse_args(_normalize_argv(
         list(sys.argv[1:] if argv is None else argv)))
     logging.basicConfig(

@@ -179,7 +179,7 @@ class Options:
     slo_error_rate: float = 0.0
     # dispatch timeline profiler (utils/timeline.py, docs/observability.md
     # "Dispatch timeline"): device HBM peak in GB/s for the roofline
-    # fraction; 0 = auto-detect from the jax platform (v5e -> 819)
+    # fraction; 0 = the device kind's published peak (utils/device.py)
     device_hbm_peak_gbps: float = 0.0
     # compile the common pow-2 batch-bucket ladder of kernel entry
     # points during warm start (jax:// only), so first-request-per-

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import asyncio
 import logging
+import time
 from dataclasses import dataclass
 from typing import Any, Iterable, Optional
 from urllib.parse import urlsplit
@@ -41,6 +42,8 @@ from .types import (
 
 logger = logging.getLogger("spicedb_kubeapi_proxy_tpu.spicedb")
 
+_YAML_LOADER = getattr(yaml, "CSafeLoader", yaml.SafeLoader)
+
 
 def apply_bootstrap_once(store: TupleStore, rel_text: str) -> bool:
     """Bootstrap-once semantics shared by the store-backed endpoints:
@@ -57,7 +60,10 @@ def apply_bootstrap_once(store: TupleStore, rel_text: str) -> bool:
             store.revision)
         return False
     # columnar bulk path (native parser when available)
+    t0 = time.perf_counter()
     store.bulk_load_text(rel_text)
+    logger.info("loaded bootstrap relationships in %.2fs",
+                time.perf_counter() - t0)
     return True
 
 
@@ -139,7 +145,9 @@ class Bootstrap:
 
     @classmethod
     def from_yaml(cls, content: str) -> "Bootstrap":
-        data = yaml.safe_load(content) or {}
+        # libyaml's loader where built: a 1M-tuple relationships block
+        # parses in ~0.3 s there against ~15 s in pure Python
+        data = yaml.load(content, Loader=_YAML_LOADER) or {}
         if not isinstance(data, dict):
             raise ValueError("bootstrap content must be a YAML mapping")
         return cls.from_mapping(data)
@@ -500,6 +508,8 @@ def create_endpoint(url: str,
                         raise EndpointConfigError(
                             f"invalid mesh {mesh_param!r} in {url!r}: {e}"
                         ) from e
+        from ..utils.device import announce
+        announce()
         if store is not None:
             kwargs["store"] = store
         ep: PermissionsEndpoint = JaxEndpoint.from_bootstrap(bootstrap,

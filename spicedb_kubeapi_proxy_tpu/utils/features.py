@@ -156,7 +156,7 @@ GATES.register("KernelIntrospect", stage=BETA, default=True)
 # chrome-trace output.  This gate is the killswitch: off, capture
 # requests are refused and the sampler thread never starts.
 GATES.register("Profiler", stage=ALPHA, default=True)
-# multi-chip mesh execution (parallel/sharding.py, parallel/compat.py,
+# multi-chip mesh execution (parallel/sharding.py,
 # ops/jax_endpoint.py _ShardedEllGraph): 2D (data x graph) shard_map
 # kernels behind `jax://?mesh=...` — row-sharded ELL tables with
 # per-iteration tiled all_gather, word-sharded batches, sharded donated

@@ -23,8 +23,8 @@ This module is the dependency-free instrument:
 - **Derived telemetry** per dispatch: achieved bytes/sec per stage
   (`authz_dispatch_bandwidth_bytes_per_sec{stage=}`), the kernel-stage
   bandwidth as a fraction of the configured device HBM peak
-  (`authz_roofline_fraction`; `--device-hbm-peak-gbps`, defaulting from
-  the detected platform), host-stall attribution
+  (`authz_roofline_fraction`; `--device-hbm-peak-gbps`, defaulting to
+  the device kind's published peak), host-stall attribution
   (`authz_dispatch_stall_seconds{cause=pack|transpose|transfer|rebuild|compile}`),
   and the transfer/compute **overlap ratio** — the fraction of
   transfer/transpose wall time during which a *different* batch's
@@ -118,12 +118,6 @@ _SERVING_STAGES = ("authn", "rule_match", "kube_upstream", "decode",
 # real recording thread id rides in args.thread)
 _TRACK_TIDS = {"host": 1, "dispatcher": 2, "device": 3, "rebuild": 4,
                "serving": 5}
-
-# published HBM peaks (GB/s) by detected jax platform; the CLI flag
-# overrides.  v5e is the hardware this repo benches on; unknown
-# platforms leave the peak unset (bandwidth still exports, the roofline
-# fraction reads 0).
-_PLATFORM_HBM_PEAK_GBPS = {"tpu": 819.0}
 
 # tracing.kernel_span name -> timeline stage.  kernel.dispatch maps to
 # "kernel": with the current packed-extraction path the capture-side
@@ -409,13 +403,12 @@ class Timeline:
 
     def set_hbm_peak(self, gbps: Optional[float]) -> None:
         """Override the device HBM peak (GB/s); None/0 restores
-        platform auto-detection."""
+        detection by device kind."""
         self._hbm_peak_gbps = gbps if gbps else None
 
     def hbm_peak_bytes_per_s(self) -> float:
-        """Configured or platform-detected peak in bytes/s; 0.0 when
-        unknown (roofline fraction then reads 0 rather than inventing a
-        denominator)."""
+        """Configured or device-kind peak in bytes/s; 0.0 when unknown
+        (no roofline fraction rather than an invented denominator)."""
         if self._hbm_peak_gbps:
             return self._hbm_peak_gbps * 1e9
         if not self._device_seen:
@@ -428,14 +421,13 @@ class Timeline:
         if self._hbm_peak_detected is None:
             # a device event exists, so the jax backend is already
             # initialized in this process — detection is a cheap lookup
-            peak = 0.0
-            try:
-                import jax
-                plat = jax.devices()[0].platform
-                peak = _PLATFORM_HBM_PEAK_GBPS.get(plat, 0.0)
-            except Exception:
-                peak = 0.0
-            self._hbm_peak_detected = peak
+            # in the published-peak table (utils/device.py), keyed by
+            # device kind; an unknown kind gives no roofline
+            import jax
+
+            from .device import hbm_peak_gbps
+            self._hbm_peak_detected = hbm_peak_gbps(
+                jax.devices()[0].device_kind) or 0.0
         return self._hbm_peak_detected * 1e9
 
     # -- recording -----------------------------------------------------------
@@ -485,7 +477,8 @@ class Timeline:
             self._bw.set(bw, stage=stage)
             if stage in _COMPUTE_STAGES:
                 peak = self.hbm_peak_bytes_per_s()
-                self._roofline.set(bw / peak if peak else 0.0)
+                if peak:
+                    self._roofline.set(bw / peak)
 
     def _compile_overlaps(self, start: float) -> bool:
         """True when a recently recorded compile slice overlaps a span
@@ -550,6 +543,8 @@ class Timeline:
                 seen.add(key)
                 self.record(stage, track, t0, bucket=bucket)
 
+        # the jitted function itself, for ahead-of-time `.lower()`
+        wrapper.__wrapped__ = fn
         return wrapper
 
     # -- views ---------------------------------------------------------------

@@ -87,6 +87,21 @@ class TestParserParity:
             assert np.array_equal(getattr(a, col), getattr(b, col)), col
 
 
+def test_native_build_is_keyed_by_source_hash(tmp_path, monkeypatch):
+    """A .so built from other source text (copied along with a tree, with
+    any mtime) is never the one loaded: the path names the source hash."""
+    from spicedb_kubeapi_proxy_tpu import native
+
+    src = tmp_path / "fastparse.cpp"
+    src.write_text("// one\n")
+    monkeypatch.setattr(native, "_SRC", str(src))
+    first = native._so_path()
+    src.write_text("// two\n")
+    assert native._so_path() != first
+    src.write_text("// one\n")
+    assert native._so_path() == first
+
+
 def canon(store, flt=None):
     return sorted(r.rel_string() for r in store.read(flt))
 

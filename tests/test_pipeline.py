@@ -398,6 +398,21 @@ class TestPrewarm:
                     if e.stage == "compile" and e.track == "device"]
         assert compiles == []
 
+    def test_prewarm_failure_stops_warm_start(self, kernel_kind,
+                                              monkeypatch):
+        """A compile the device refuses during warm start (an HBM OOM, a
+        program the compiler rejects) is raised to the server's startup
+        instead of being logged and deferred to the first request."""
+        jx, _ = make_pair(seed=37)
+        jx.warm_start()  # build the graph without prewarm
+
+        def refuse(**_):
+            raise RuntimeError("RESOURCE_EXHAUSTED: out of HBM")
+
+        monkeypatch.setattr(jx._graph, "prewarm", refuse)
+        with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+            jx.warm_start(prewarm=True)
+
     def test_off_diagonal_bulk_check_compiles_nothing(self, kernel_kind):
         """A bulk check whose request count and distinct-subject count
         land in DIFFERENT pow-2 buckets must still hit a prewarmed jit

@@ -70,34 +70,6 @@ def build(seed=0, n_users=40, n_groups=8, n_tenants=3, n_ns=6, n_pods=60):
     return schema, store, prog
 
 
-class TestShardMapCompat:
-    """parallel/compat.shard_map must resolve on the pinned jax (where
-    `jax.shard_map` does not exist) and translate the modern
-    `check_vma=` kwarg down to whatever the resolved impl accepts."""
-
-    def test_resolves_and_runs(self):
-        import jax.numpy as jnp
-        from jax.sharding import PartitionSpec as P
-
-        from spicedb_kubeapi_proxy_tpu.parallel.compat import shard_map
-
-        mesh = make_mesh(data=2, graph=4)
-        fn = shard_map(
-            lambda x: jax.lax.psum(x.sum(), "data")[None],
-            mesh=mesh, in_specs=(P("data"),), out_specs=P(None),
-            check_vma=False)
-        x = jnp.arange(8, dtype=jnp.int32)
-        assert int(fn(x)[0]) == 28
-
-    def test_check_kwarg_translated(self):
-        from spicedb_kubeapi_proxy_tpu.parallel import compat
-
-        # whichever jax is pinned, the shim must have found the impl and
-        # (on every release so far) its replication-check kwarg
-        assert callable(compat._SHARD_MAP)
-        assert compat._CHECK_KWARG in ("check_vma", "check_rep")
-
-
 class TestMesh:
     def test_eight_devices_available(self):
         assert len(jax.devices()) == 8
