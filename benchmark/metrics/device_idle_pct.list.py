@@ -1,0 +1,7 @@
+"""Share of the traced stretch in which no operation ran on the device
+(1 - union of device op intervals / stretch), in %, kubectl mix."""
+from readers import idle_pct
+
+
+def read(ctx):
+    return idle_pct(ctx)

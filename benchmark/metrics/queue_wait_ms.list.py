@@ -1,0 +1,6 @@
+"""Mean ms per list waiting in the dispatcher's queue (phase span)."""
+from readers import phase_ms
+
+
+def read(ctx):
+    return phase_ms(ctx, "list", "queue_wait")
