@@ -362,10 +362,14 @@ def expect_list(proxy: Proxy, user: str, want: set) -> tuple:
 
 
 def phase_seconds(proxy: Proxy) -> dict:
-    """Cumulative seconds per request phase (authz_request_phase_seconds)."""
+    """Cumulative seconds per request phase (authz_request_phase_seconds,
+    summed over verbs)."""
     _, body, _ = http(proxy.url, "GET", "/metrics", "smoke")
-    return {lab["phase"]: v for lab, v in metric_samples(
-        body.decode(), "authz_request_phase_seconds_sum")}
+    out: dict = {}
+    for lab, v in metric_samples(body.decode(),
+                                 "authz_request_phase_seconds_sum"):
+        out[lab["phase"]] = out.get(lab["phase"], 0.0) + v
+    return out
 
 
 def choose_users(data: Data, proxy: Proxy) -> tuple:

@@ -18,7 +18,8 @@ What it proves end to end:
 - kernel introspection & workload attribution: after real mixed
   traffic the measured sweep histograms carry samples, `/debug/workload`
   attributes device seconds per (type, permission) and its total
-  reconciles with cumulative `authz_kernel_time_seconds` within 5%,
+  reconciles with the cumulative `ready` stage of
+  `authz_dispatch_stage_seconds` within 5%,
   and `/debug/profile` returns non-empty collapsed stacks;
 - admission control (second server, `--shed-queue-depth` +
   `jax://?max_queue_depth=`): driving concurrent read waves past the
@@ -100,9 +101,9 @@ REQUIRED_FAMILIES = (
     "authz_jit_cache_misses_total",
     "authz_jit_cache_entries",
     "authz_slo_burn_rate",
-    "authz_kernel_time_seconds",
+    # stages of each fused device call (utils/tracing.py)
+    "authz_dispatch_stage_seconds",
     # dispatch timeline (utils/timeline.py)
-    "authz_dispatch_stall_seconds",
     "authz_dispatch_bandwidth_bytes_per_sec",
     "authz_roofline_fraction",
     "authz_dispatch_overlap_ratio",
@@ -322,12 +323,12 @@ async def main() -> None:
             fail(f"/debug/workload payload missing leopard_candidates: "
                  f"{sorted(wl)}")
         # total device seconds must reconcile with the cumulative
-        # kernel-time histogram (same hook, same seconds) within 5%
+        # `ready` stage of the dispatch-stage histogram (the same span's
+        # seconds) within 5%
         metric_s = 0.0
         for line in text.splitlines():
-            if (line.startswith("authz_kernel_time_seconds_sum{")
-                    and ('phase="kernel.device"' in line
-                         or 'phase="kernel.dispatch"' in line)):
+            if (line.startswith("authz_dispatch_stage_seconds_sum{")
+                    and 'stage="ready"' in line):
                 metric_s += float(line.split()[-1])
         total_s = wl.get("total_device_s", 0.0)
         if metric_s <= 0 or total_s <= 0:
@@ -335,7 +336,8 @@ async def main() -> None:
                  f"workload {total_s})")
         if abs(total_s - metric_s) > 0.05 * metric_s:
             fail(f"/debug/workload total_device_s {total_s:.4f}s does not "
-                 f"reconcile with authz_kernel_time_seconds {metric_s:.4f}s "
+                 f"reconcile with authz_dispatch_stage_seconds{{stage="
+                 f"\"ready\"}} {metric_s:.4f}s "
                  f"(> 5% apart)")
         resp = await alice.get("/debug/profile?seconds=0.2")
         if resp.status != 200:

@@ -203,9 +203,7 @@ def with_authorization(handler: Handler, failed: Handler,
 
         # rule matching + CEL condition filtering are one attribution
         # phase: both walk the matched rule set against the request
-        from ..utils import timeline
-        with span("match", phase=True) as match_attrs, \
-                timeline.serving_span("rule_match"):
+        with span("match", phase=True) as match_attrs:
             matching_rules = matcher_ref().match(info)
             filtered_rules: list = []
             cel_failed = False

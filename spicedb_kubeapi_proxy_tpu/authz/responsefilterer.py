@@ -303,24 +303,22 @@ class StandardResponseFilterer(ResponseFilterer):
             raise FilterError(
                 f"unsupported media type {media} for gvk {gvk}")
 
-        from ..utils import timeline
         try:
-            with timeline.serving_span("decode",
-                                       nbytes=len(resp.body or b"")):
+            with span("decode", nbytes=len(resp.body or b"")):
                 decoded = json.loads(resp.body) if resp.body else {}
         except ValueError as e:
             raise FilterError(f"failed to decode response body: {e}") from e
 
         if len(info.parts) == 1:
             # list response
-            with timeline.serving_span("filter"):
+            with span("filter"):
                 err = self._filter_list(decoded, result)
-            with timeline.serving_span("serialize") as ser_attrs:
+            with span("serialize") as ser_attrs:
                 body = b"" if err else json.dumps(decoded).encode()
                 ser_attrs["nbytes"] = len(body)
             self._write_resp(resp, body, err)
         else:
-            with timeline.serving_span("filter"):
+            with span("filter"):
                 err = self._filter_object(decoded, result)
             self._write_resp(resp, resp.body if not err else b"", err)
 

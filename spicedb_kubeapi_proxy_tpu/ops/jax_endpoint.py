@@ -317,9 +317,9 @@ _log = logging.getLogger(__name__)
 # The pipelined entry points return un-materialized device arrays; a
 # small waiter pool parks one thread per in-flight batch on the
 # completed future (block_until_ready), which is the only host-visible
-# instant the device window closes — that gives the timeline an honest
-# `kernel` slice under async dispatch (the dispatching call itself is
-# launch-only) — then drains the D2H as the `transfer` slice.  Sized
+# instant the device window closes — that gives the call an honest
+# `ready` stage under async dispatch (the dispatching call itself is
+# launch-only) — then drains the D2H as the `transfer` stage.  Sized
 # above any sane --pipeline-depth; excess submissions just queue.
 
 _READBACK_POOL = None
@@ -389,21 +389,26 @@ class _GenState:
 
 
 def _start_readback(dev, batch_id, bucket: int, sweep_bytes: int,
-                    kind: str, on_error=None, tel=None, verb=None,
-                    comp=None, kernel="ell"):
+                    kind: str, on_error=None, tel=None, comp=None,
+                    kernel="ell"):
     """Submit the async readback of a dispatched device result; returns
     a concurrent.futures.Future resolving to the host numpy array.
     `on_error` (e.g. discarding the donated arena chain) runs before the
     exception propagates to the waiter.
 
+    The waiter marks two stages of the `kind` call (lookup/check):
+    `ready` (launch end -> block_until_ready returns: device-stream
+    queueing plus the call's own kernel) and `transfer` (ready -> host
+    array in hand: the sweep-trace read and the D2H copy).  It runs in
+    the submitter's context, so both land in every co-batched trace.
+
     `tel` (KernelIntrospect) is the sweep-trace device array the
-    pipelined kernels return alongside the result: it is materialized
-    AFTER block_until_ready (no extra sync — the whole computation is
-    already done) and turns the kernel slice's byte tag from the modeled
-    one-sweep floor into measured `iterations x sweep_bytes`.  `comp`
-    (the batch's (type, permission, rows) composition) rides the device
-    window attrs into the workload cost-attribution plane."""
-    t0 = timeline.now()
+    pipelined kernels return alongside the result: read after the
+    device window closes, it turns the kernel slice's byte tag from the
+    modeled one-sweep floor into measured `iterations x sweep_bytes`.
+    `comp` (the batch's (type, permission, rows) composition) carries
+    the device window into the workload cost-attribution plane."""
+    ctx = contextvars.copy_context()
 
     def wait_and_fetch():
         try:
@@ -411,31 +416,22 @@ def _start_readback(dev, batch_id, bucket: int, sweep_bytes: int,
             # dying here must fail its batch fast, discard the donated
             # arena via on_error, and leave the ledger consistent
             fail_point("readbackWaiter")
-            dev.block_until_ready()
-            t_ready = timeline.now()
-            nbytes, measured = sweep_bytes, False
-            if tel is not None:
-                rec = workload.note_sweep(kernel, verb or kind,
-                                          np.asarray(tel))
-                if rec is not None and rec.iterations > 0:
-                    nbytes = rec.iterations * sweep_bytes
-                    measured = True
-                    workload.WORKLOAD.note_depth(comp, rec.iterations)
-            # the true device window: dispatch -> results ready (includes
-            # queueing behind earlier batches on the device stream, same
-            # contract as the serial path's host window)
-            timeline.record("kernel", "device", t0, t_ready,
-                            batch=batch_id, bucket=bucket,
-                            nbytes=nbytes, measured=measured)
-            tracing.note_device_window(
-                "kernel.device", {"kind": kind, "bucket": bucket,
-                                  "workload": comp},
-                t_ready - t0)
-            dev.copy_to_host_async()
-            host = np.asarray(dev)
-            timeline.record("transfer", "device", t_ready,
-                            batch=batch_id, bucket=bucket,
-                            nbytes=int(host.nbytes))
+            ready = tracing.span("ready", fused=kind, batch_id=batch_id,
+                                 bucket=bucket, nbytes=sweep_bytes)
+            with ready:
+                dev.block_until_ready()
+            workload.note_device_time(comp, "ready", ready.seconds)
+            with tracing.span("transfer", fused=kind, batch_id=batch_id,
+                              bucket=bucket) as a:
+                if tel is not None:
+                    rec = workload.note_sweep(kernel, kind, np.asarray(tel))
+                    if rec is not None and rec.iterations > 0:
+                        timeline.TIMELINE.retag_kernel(
+                            batch_id, rec.iterations * sweep_bytes)
+                        workload.WORKLOAD.note_depth(comp, rec.iterations)
+                dev.copy_to_host_async()
+                host = np.asarray(dev)
+                a["nbytes"] = int(host.nbytes)
             return host
         except Exception:
             if on_error is not None:
@@ -445,7 +441,7 @@ def _start_readback(dev, batch_id, bucket: int, sweep_bytes: int,
                     _log.exception("readback error cleanup failed")
             raise
 
-    return _readback_pool().submit(wait_and_fetch)
+    return _readback_pool().submit(ctx.run, wait_and_fetch)
 
 
 def _ids_for(ids: np.ndarray, idx: np.ndarray, ph, mask) -> tuple:
@@ -2086,6 +2082,14 @@ class JaxEndpoint(PermissionsEndpoint):
         self._apply_pending()
         return self._graph
 
+    def _drain_before_pack(self) -> None:
+        """Drain store deltas ahead of a call's `pack` stage, so a
+        compaction or rebuild the drain runs (stages of their own) is
+        never counted as packing; the drain `pack` repeats under its
+        own lock hold is a no-op unless a write landed in between."""
+        with self._lock:
+            self._apply_pending()
+
     # -- off-loop rebuild machinery ------------------------------------------
 
     _SPARE_LOW_FRACTION = 0.25
@@ -2365,9 +2369,24 @@ class JaxEndpoint(PermissionsEndpoint):
                 results[it[0]] = (int(bit) * 2, rev)
 
     def _check_batch_capture(self, reqs: list) -> dict:
+        """Phase 1 of a fused check batch: the delta drain, the `pack`
+        stage (query encoding under the lock, the per-row loop and the
+        Leopard fill), then `launch` (pipelined: the async dispatch; the readback waiter
+        marks `ready` and `transfer`) or the blocking `ready` call of the
+        serial path."""
         bid = timeline.next_batch()
-        with tracing.span("kernel.prepare", kind="check", batch=len(reqs)), \
-                self._lock:
+        self._drain_before_pack()
+        with tracing.span("pack", fused="check", batch_id=bid,
+                          rows=len(reqs)) as pack:
+            ctx, kernel_call = self._check_pack(reqs, bid, pack)
+        if kernel_call is not None:
+            self._check_dispatch(ctx, *kernel_call)
+        return ctx
+
+    def _check_pack(self, reqs: list, bid: int, pack: dict) -> tuple:
+        """The `pack` stage of a fused check: -> (ctx, kernel call args
+        or None when no row needs the kernel)."""
+        with self._lock:
             # checked_at = the revision the drained graph actually
             # reflects (tracked through rebuilds and applied deltas) —
             # reading store.revision here instead would race loop-thread
@@ -2375,12 +2394,10 @@ class JaxEndpoint(PermissionsEndpoint):
             # results to a revision the kernel never evaluated
             graph = self._current_graph()
             rev = self._graph_revision
-            # timeline "pack": host query encoding + gather-list build
-            # (starts AFTER the delta drain so rebuild/compact time is
-            # never misattributed to packing)
-            t_pack = timeline.now()
             q_arr, cols, unknown = self._encode_subjects(
                 graph, [r.subject for r in reqs])
+            pack["bucket"] = len(q_arr)
+            pack["nbytes"] = int(q_arr.nbytes)
             gather_idx: list[int] = []
             gather_col: list[int] = []
             kernel_rows: list[int] = []  # positions in reqs served by kernel
@@ -2448,8 +2465,6 @@ class JaxEndpoint(PermissionsEndpoint):
                 kernel_rows.append(i)
             if leo_rows:
                 self.stats["leopard_checks"] += len(leo_rows)
-            timeline.record("pack", "host", t_pack, batch=bid,
-                            bucket=len(q_arr), nbytes=int(q_arr.nbytes))
             if kernel_rows:
                 snap = graph.snapshot()
                 self.stats["kernel_calls"] += 1
@@ -2461,9 +2476,6 @@ class JaxEndpoint(PermissionsEndpoint):
                 devtel.OCCUPANCY.record("check", used, len(q_arr) - used)
                 devtel.LEDGER.note_scratch(
                     int(q_arr.nbytes) + 8 * len(gather_idx))
-        # device dispatch runs OUTSIDE the lock: the snapshot is
-        # immutable, so concurrent drains/queries proceed instead of
-        # queueing behind a hundreds-of-ms kernel hold.
         ctx = {"reqs": reqs, "results": results, "kernel_rows": kernel_rows,
                "oracle_rows": oracle_rows, "rev": rev, "batch_id": bid}
         if leo_rows:
@@ -2471,9 +2483,7 @@ class JaxEndpoint(PermissionsEndpoint):
             # measured depth on indexed pairs is 1 by construction —
             # recorded through note_batch so /debug/workload shows the
             # collapse the index buys
-            with tracing.kernel_span("kernel.leopard", kind="check",
-                                     rows=len(leo_rows)) as a:
-                a["batch_id"] = bid
+            with tracing.span("kernel.leopard", rows=len(leo_rows)):
                 self._leo_check_fill(leo_rows, results, rev)
             workload.WORKLOAD.note_batch(
                 workload.comp_rows([reqs[i] for (i, _v, _r, _c)
@@ -2482,91 +2492,96 @@ class JaxEndpoint(PermissionsEndpoint):
         if oracle_rows:
             workload.WORKLOAD.note_oracle(
                 workload.comp_rows([reqs[i] for i in oracle_rows]))
-        if kernel_rows:
-            # (type, permission) composition of the kernel-served rows:
-            # rides the device-window span attrs into the workload
-            # cost-attribution plane (utils/workload.py)
-            comp = workload.comp_rows([reqs[i] for i in kernel_rows])
-            occ = used / len(q_arr) if len(q_arr) else None
-            pipe = (getattr(graph, "run_checks3_device", None)
-                    if _pipeline_on() else None)
-            if pipe is not None:
-                workload.WORKLOAD.note_batch(comp, "check", occupancy=occ)
-                # hotpath: begin pipelined check dispatch (device does the
-                # word/bit split and the readback is async — reintroducing
-                # host numpy staging here is the regression M003 guards)
-                with tracing.kernel_span("kernel.launch", kind="check",
-                                         rows=len(kernel_rows),
-                                         bucket=len(q_arr)) as a:
-                    a["batch_id"] = bid
-                    dev, tel, kern = pipe(q_arr, gather_idx, gather_col,
-                                          snap=snap)
-                key = kern.arena_key(len(q_arr))
-                ctx["readback"] = _start_readback(
-                    dev, bid, bucket=len(q_arr),
-                    sweep_bytes=_sweep_bytes(graph, len(q_arr)),
-                    kind="check",
-                    on_error=lambda: kern.discard_arena(key),
-                    tel=tel, verb="check", comp=comp,
-                    kernel=getattr(kern, "kernel_name", "ell"))
-                # hotpath: end
-            else:
-                with tracing.kernel_span("kernel.device", kind="check",
-                                         rows=len(kernel_rows),
-                                         bucket=len(q_arr)) as a:
-                    # timeline tags: fused-batch id + modeled one-sweep
-                    # bytes (the roofline lower bound) ride the span
-                    # attrs into the device track
-                    a["batch_id"] = bid
-                    a["nbytes"] = _sweep_bytes(graph, len(q_arr))
-                    a["workload"] = comp
-                    workload.take_last_sweep()  # drop any stale record
-                    ctx["out"] = graph.run_checks3(q_arr, gather_idx,
-                                                   gather_col, snap=snap)
-                    # serial path: the sweep record is available
-                    # synchronously (same thread) — upgrade the span's
-                    # byte tag to measured iterations x one-sweep bytes
-                    rec = workload.take_last_sweep()
-                    if rec is not None and rec.iterations > 0:
-                        a["nbytes"] *= rec.iterations
-                        a["measured"] = True
-                    workload.WORKLOAD.note_batch(
-                        comp, "check",
-                        rec.iterations if rec is not None else None, occ)
-        return ctx
+        if not kernel_rows:
+            return ctx, None
+        # (type, permission) composition of the kernel-served rows:
+        # attributes the call's device window in the workload
+        # cost-attribution plane (utils/workload.py)
+        comp = workload.comp_rows([reqs[i] for i in kernel_rows])
+        occ = used / len(q_arr) if len(q_arr) else None
+        return ctx, (graph, snap, q_arr, gather_idx, gather_col, comp, occ)
+
+    def _check_dispatch(self, ctx: dict, graph, snap, q_arr, gather_idx,
+                        gather_col, comp, occ) -> None:
+        """Run the fused check's kernel outside the lock: the snapshot is
+        immutable, so concurrent drains/queries proceed instead of
+        queueing behind a hundreds-of-ms kernel hold."""
+        bid = ctx["batch_id"]
+        rows = len(ctx["kernel_rows"])
+        pipe = (getattr(graph, "run_checks3_device", None)
+                if _pipeline_on() else None)
+        if pipe is not None:
+            workload.WORKLOAD.note_batch(comp, "check", occupancy=occ)
+            # hotpath: begin pipelined check dispatch (device does the
+            # word/bit split and the readback is async — reintroducing
+            # host numpy staging here is the regression M003 guards)
+            with tracing.span("launch", fused="check", batch_id=bid,
+                              rows=rows, bucket=len(q_arr)):
+                dev, tel, kern = pipe(q_arr, gather_idx, gather_col,
+                                      snap=snap)
+            key = kern.arena_key(len(q_arr))
+            ctx["readback"] = _start_readback(
+                dev, bid, bucket=len(q_arr),
+                sweep_bytes=_sweep_bytes(graph, len(q_arr)),
+                kind="check",
+                on_error=lambda: kern.discard_arena(key),
+                tel=tel, comp=comp,
+                kernel=getattr(kern, "kernel_name", "ell"))
+            # hotpath: end
+            return
+        # serial path: one blocking call launches, runs and reads back
+        ready = tracing.span("ready", fused="check", batch_id=bid,
+                             rows=rows, bucket=len(q_arr),
+                             nbytes=_sweep_bytes(graph, len(q_arr)))
+        with ready as a:
+            workload.take_last_sweep()  # drop any stale record
+            ctx["out"] = graph.run_checks3(q_arr, gather_idx, gather_col,
+                                           snap=snap)
+            # the sweep record is available synchronously (same thread)
+            # — upgrade the span's byte tag to measured iterations x
+            # one-sweep bytes
+            rec = workload.take_last_sweep()
+            if rec is not None and rec.iterations > 0:
+                a["nbytes"] *= rec.iterations
+                a["measured"] = True
+        workload.note_device_time(comp, "ready", ready.seconds)
+        workload.WORKLOAD.note_batch(
+            comp, "check", rec.iterations if rec is not None else None, occ)
 
     def _check_batch_finish(self, ctx: dict) -> list:
-        """Phase 2 of a fused check batch: block on the async readback
-        (pipelined) or consume the already-host result (serial), then
-        assemble CheckResults.  Oracle fallbacks evaluate the LIVE store
-        here, outside the endpoint lock, and carry its revision rather
-        than claiming the graph snapshot's."""
+        """Phase 2 of a fused check batch: `wait` on the async readback
+        (pipelined) or take the already-host result (serial), then
+        `assemble` CheckResults.  Oracle fallbacks evaluate the LIVE
+        store here, outside the endpoint lock, and carry its revision
+        rather than claiming the graph snapshot's."""
         results = ctx["results"]
         fut = ctx.get("readback")
         if fut is not None:
-            with tracing.kernel_span("kernel.wait", kind="check") as a:
-                a["batch_id"] = ctx["batch_id"]
+            with tracing.span("wait", fused="check",
+                              batch_id=ctx["batch_id"]):
                 out = fut.result()
         else:
             out = ctx.get("out")
-        if out is not None:
-            rev = ctx["rev"]
-            for j, row in enumerate(ctx["kernel_rows"]):
-                results[row] = (int(out[j]), rev)
-        oracle_rows = ctx["oracle_rows"]
-        if oracle_rows:
-            with tracing.span("kernel.oracle", kind="check",
-                              rows=len(oracle_rows)):
-                for i in oracle_rows:
-                    r = ctx["reqs"][i]
-                    results[i] = (self._oracle.check3(r.resource, r.permission,
-                                                      r.subject),
-                                  self.store.revision)
-        oracle_set = set(oracle_rows)
-        return [CheckResult(permissionship=self._TRISTATE[v],
-                            checked_at=at,
-                            source="oracle" if i in oracle_set else "kernel")
-                for i, (v, at) in enumerate(results)]
+        with tracing.span("assemble", fused="check",
+                          batch_id=ctx["batch_id"], rows=len(results)):
+            if out is not None:
+                rev = ctx["rev"]
+                for j, row in enumerate(ctx["kernel_rows"]):
+                    results[row] = (int(out[j]), rev)
+            oracle_rows = ctx["oracle_rows"]
+            if oracle_rows:
+                with tracing.span("kernel.oracle", rows=len(oracle_rows)):
+                    for i in oracle_rows:
+                        r = ctx["reqs"][i]
+                        results[i] = (self._oracle.check3(
+                            r.resource, r.permission, r.subject),
+                            self.store.revision)
+            oracle_set = set(oracle_rows)
+            return [CheckResult(permissionship=self._TRISTATE[v],
+                                checked_at=at,
+                                source="oracle" if i in oracle_set
+                                else "kernel")
+                    for i, (v, at) in enumerate(results)]
 
     def _report_suppressed(self, n: int, sample: list, context,
                            retry: bool = False) -> None:
@@ -2597,7 +2612,7 @@ class JaxEndpoint(PermissionsEndpoint):
         callers did.  The caller's context is copied across the thread
         hop so the active request trace (utils/tracing.py) — including a
         dispatch-fanned-out batch trace — still resolves in the executor
-        and kernel spans land in the right request(s)."""
+        and the dispatch-stage spans land in the right request(s)."""
         loop = asyncio.get_running_loop()
         ctx = contextvars.copy_context()
         return await loop.run_in_executor(None, lambda: ctx.run(fn, *args))
@@ -2668,7 +2683,9 @@ class JaxEndpoint(PermissionsEndpoint):
         oracle = False
         leo_hit = None  # (fragment view, closure column) when indexed
         bid = timeline.next_batch()
-        with self._lock:
+        self._drain_before_pack()
+        with tracing.span("pack", fused="lookup", batch_id=bid,
+                          rows=1) as pack, self._lock:
             graph = self._current_graph()
             if (resource_type, permission) in self._stale_pairs:
                 # quarantined pair (off-loop rebuild in flight): the
@@ -2686,11 +2703,9 @@ class JaxEndpoint(PermissionsEndpoint):
                                                permission)) is None:
                 oracle = True
             else:
-                t_pack = timeline.now()
                 q_arr, cols, unknown = self._encode_subjects(graph, [subject])
-                timeline.record("pack", "host", t_pack, batch=bid,
-                                bucket=len(q_arr),
-                                nbytes=int(q_arr.nbytes))
+                pack["bucket"] = len(q_arr)
+                pack["nbytes"] = int(q_arr.nbytes)
                 if subject in unknown:
                     oracle = True
                 else:
@@ -2724,7 +2739,7 @@ class JaxEndpoint(PermissionsEndpoint):
         if oracle:
             # host evaluation outside the lock (reads the live store)
             workload.WORKLOAD.note_oracle([(resource_type, permission, 1)])
-            with tracing.span("kernel.oracle", kind="lookup"):
+            with tracing.span("kernel.oracle", rows=1):
                 return AnnotatedIds(
                     self._oracle.lookup_resources(resource_type, permission,
                                                   subject),
@@ -2734,47 +2749,44 @@ class JaxEndpoint(PermissionsEndpoint):
             # closure-plane lookup: one word-column slice of the
             # fragment plane replaces the fixpoint kernel (depth 1)
             (plane, plane_rows), lcol = leo_hit
-            with tracing.kernel_span("kernel.leopard", kind="lookup") as a:
-                a["batch_id"] = bid
+            with tracing.span("kernel.leopard", rows=1):
                 wordcol = np.asarray(plane[:plane_rows, lcol // 32])
                 idx = np.nonzero((wordcol >> np.uint32(lcol % 32))
                                  & np.uint32(1))[0]
             workload.WORKLOAD.note_batch(
                 comp, "lookup", 1, 1 / len(q_arr) if len(q_arr) else None)
             self._leopard.note_hits("lookup", 1)
-            t_ext = timeline.now()
-            out, bad_n, bad_sample = _ids_for(ids, idx, ph, mask)
-            timeline.record("extract", "host", t_ext, batch=bid)
-            if bad_n:
-                self._report_suppressed(bad_n, bad_sample, _forensic,
-                                        retry=retry)
-            return AnnotatedIds(out, source="kernel"), bad_n
-        # kernel + extraction outside the lock (immutable snapshot)
-        with tracing.kernel_span("kernel.device", kind="lookup",
-                                 bucket=len(q_arr)) as a:
-            a["batch_id"] = bid
-            a["nbytes"] = _sweep_bytes(graph, len(q_arr))
-            a["workload"] = comp
-            workload.take_last_sweep()  # drop any stale record
-            if hasattr(graph, "run_lookup_packed"):
-                packed = graph.run_lookup_packed(rng[0], rng[1], q_arr,
-                                                 snap=snap)
-                idx = _word_col_indices(
-                    np.ascontiguousarray(packed[:, col // 32]), col % 32)
-            else:
-                bitmap = graph.run_lookup(rng[0], rng[1], q_arr, snap=snap)
-                idx = np.nonzero(bitmap[:, col])[0]
-            rec = workload.take_last_sweep()
-            if rec is not None and rec.iterations > 0:
-                a["nbytes"] *= rec.iterations
-                a["measured"] = True
+        else:
+            # kernel outside the lock (immutable snapshot); the serial
+            # call launches, runs and reads back in one blocking `ready`
+            ready = tracing.span("ready", fused="lookup", batch_id=bid,
+                                 bucket=len(q_arr),
+                                 nbytes=_sweep_bytes(graph, len(q_arr)))
+            with ready as a:
+                workload.take_last_sweep()  # drop any stale record
+                if hasattr(graph, "run_lookup_packed"):
+                    packed = graph.run_lookup_packed(rng[0], rng[1], q_arr,
+                                                     snap=snap)
+                else:
+                    bitmap = graph.run_lookup(rng[0], rng[1], q_arr,
+                                              snap=snap)
+                rec = workload.take_last_sweep()
+                if rec is not None and rec.iterations > 0:
+                    a["nbytes"] *= rec.iterations
+                    a["measured"] = True
+            workload.note_device_time(comp, "ready", ready.seconds)
             workload.WORKLOAD.note_batch(
                 comp, "lookup",
                 rec.iterations if rec is not None else None,
                 1 / len(q_arr) if len(q_arr) else None)
-        t_ext = timeline.now()
-        out, bad_n, bad_sample = _ids_for(ids, idx, ph, mask)
-        timeline.record("extract", "host", t_ext, batch=bid)
+        with tracing.span("extract", fused="lookup", batch_id=bid, rows=1):
+            if leo_hit is None:
+                if hasattr(graph, "run_lookup_packed"):
+                    idx = _word_col_indices(
+                        np.ascontiguousarray(packed[:, col // 32]), col % 32)
+                else:
+                    idx = np.nonzero(bitmap[:, col])[0]
+            out, bad_n, bad_sample = _ids_for(ids, idx, ph, mask)
         if bad_n:
             self._report_suppressed(bad_n, bad_sample, _forensic, retry=retry)
         return AnnotatedIds(out, source="kernel"), bad_n
@@ -2824,9 +2836,22 @@ class JaxEndpoint(PermissionsEndpoint):
         N+1's kernel during N's D2H instead of idling (the dispatcher's
         double-buffer drain, spicedb/dispatch.py)."""
         self.schema.definition(resource_type)
+        bid = timeline.next_batch()
+        self._drain_before_pack()
+        with tracing.span("pack", fused="lookup", batch_id=bid,
+                          rows=len(subjects)) as pack:
+            ctx, kernel_call = self._lookup_pack(
+                resource_type, permission, subjects, bid, pack)
+        if kernel_call is not None:
+            self._lookup_dispatch(ctx, *kernel_call)
+        return ctx
+
+    def _lookup_pack(self, resource_type: str, permission: str,
+                     subjects: list, bid: int, pack: dict) -> tuple:
+        """The `pack` stage of a fused lookup: -> (ctx, kernel call args
+        or None when the oracle or the Leopard plane answers)."""
         all_oracle = False
         leo = None  # (fragment view, {query col -> closure col}) if indexed
-        bid = timeline.next_batch()
         with self._lock:
             graph = self._current_graph()
             if (resource_type, permission) in self._stale_pairs:
@@ -2841,11 +2866,9 @@ class JaxEndpoint(PermissionsEndpoint):
                                                permission)) is None:
                 all_oracle = True
             else:
-                t_pack = timeline.now()
                 q_arr, cols, unknown = self._encode_subjects(graph, subjects)
-                timeline.record("pack", "host", t_pack, batch=bid,
-                                bucket=len(q_arr),
-                                nbytes=int(q_arr.nbytes))
+                pack["bucket"] = len(q_arr)
+                pack["nbytes"] = int(q_arr.nbytes)
                 used = len(set(cols.values()))
                 devtel.OCCUPANCY.record("lookup", used, len(q_arr) - used)
                 snap = graph.snapshot()
@@ -2884,19 +2907,25 @@ class JaxEndpoint(PermissionsEndpoint):
             workload.WORKLOAD.note_oracle(
                 [(resource_type, permission, len(subjects))])
             ctx["all_oracle"] = True
-            return ctx
+            return ctx, None
         comp = [(resource_type, permission, len(subjects))]
         occ = used / len(q_arr) if len(q_arr) else None
+        ctx.update(cols=cols, unknown=unknown, ids=ids, mask=mask, ph=ph,
+                   forensic=_forensic)
         if leo is not None:
             # closure-plane batch: word-column slices of the fragment
             # plane replace the fixpoint kernel (measured depth 1)
             ctx["leopard"] = leo
             workload.WORKLOAD.note_batch(comp, "lookup", 1, occ)
             self._leopard.note_hits("lookup", len(leo[1]))
-            ctx.update(cols=cols, unknown=unknown, ids=ids, mask=mask,
-                       ph=ph, forensic=_forensic)
-            return ctx
-        # kernel dispatch outside the lock (immutable snapshot)
+            return ctx, None
+        return ctx, (graph, snap, rng, q_arr, comp, occ)
+
+    def _lookup_dispatch(self, ctx: dict, graph, snap, rng, q_arr, comp,
+                         occ) -> None:
+        """Run the fused lookup's kernel outside the lock (immutable
+        snapshot)."""
+        bid = ctx["batch_id"]
         pipe = None
         if _pipeline_on():
             pipe = (getattr(graph, "run_lookup_packed_T_device", None)
@@ -2908,61 +2937,57 @@ class JaxEndpoint(PermissionsEndpoint):
             # device array reads back asynchronously (reintroducing the
             # host `.T`/ascontiguousarray copy here is the regression
             # M003 guards)
-            with tracing.kernel_span("kernel.launch", kind="lookup_batch",
-                                     batch=len(subjects),
-                                     bucket=len(q_arr)) as a:
-                a["batch_id"] = bid
+            with tracing.span("launch", fused="lookup", batch_id=bid,
+                              rows=len(ctx["subjects"]), bucket=len(q_arr)):
                 dev, tel, kern = pipe(rng[0], rng[1], q_arr, snap=snap)
             key = kern.arena_key(len(q_arr))
             ctx["readback"] = _start_readback(
                 dev, bid, bucket=len(q_arr),
                 sweep_bytes=_sweep_bytes(graph, len(q_arr)),
-                kind="lookup_batch",
+                kind="lookup",
                 on_error=lambda: kern.discard_arena(key),
-                tel=tel, verb="lookup", comp=comp,
+                tel=tel, comp=comp,
                 kernel=getattr(kern, "kernel_name", "ell"))
             # hotpath: end
-        else:
-            with tracing.kernel_span("kernel.dispatch", kind="lookup_batch",
-                                     batch=len(subjects),
-                                     bucket=len(q_arr)) as a:
-                a["batch_id"] = bid
-                a["nbytes"] = _sweep_bytes(graph, len(q_arr))
-                a["workload"] = comp
-                workload.take_last_sweep()  # drop any stale record
-                if hasattr(graph, "run_lookup_packed"):
-                    # packed fast path: per-column shift/AND/nonzero over
-                    # one uint32 word column — never materializes the 32x
-                    # larger bool bitmap or its [B, L] transpose.
-                    # Transposed on device so the transfer lands
-                    # contiguous per word column.
-                    packed_T = graph.run_lookup_packed(rng[0], rng[1], q_arr,
-                                                       snap=snap).T
-                    if isinstance(packed_T, jax.Array):
-                        packed_T.copy_to_host_async()
-                    ctx["packed_T"] = packed_T
-                else:
-                    ctx["bitmap"] = graph.run_lookup(rng[0], rng[1], q_arr,
-                                                     snap=snap)
-                rec = workload.take_last_sweep()
-                if rec is not None and rec.iterations > 0:
-                    a["nbytes"] *= rec.iterations
-                    a["measured"] = True
-                workload.WORKLOAD.note_batch(
-                    comp, "lookup",
-                    rec.iterations if rec is not None else None, occ)
-        ctx.update(cols=cols, unknown=unknown, ids=ids, mask=mask, ph=ph,
-                   forensic=_forensic)
-        return ctx
+            return
+        # serial path: the blocking call launches and runs the kernel;
+        # a packed result's D2H starts here and lands in extraction
+        ready = tracing.span("ready", fused="lookup", batch_id=bid,
+                             rows=len(ctx["subjects"]), bucket=len(q_arr),
+                             nbytes=_sweep_bytes(graph, len(q_arr)))
+        with ready as a:
+            workload.take_last_sweep()  # drop any stale record
+            if hasattr(graph, "run_lookup_packed"):
+                # packed fast path: per-column shift/AND/nonzero over
+                # one uint32 word column — never materializes the 32x
+                # larger bool bitmap or its [B, L] transpose.
+                # Transposed on device so the transfer lands
+                # contiguous per word column.
+                packed_T = graph.run_lookup_packed(rng[0], rng[1], q_arr,
+                                                   snap=snap).T
+                if isinstance(packed_T, jax.Array):
+                    packed_T.copy_to_host_async()
+                ctx["packed_T"] = packed_T
+            else:
+                ctx["bitmap"] = graph.run_lookup(rng[0], rng[1], q_arr,
+                                                 snap=snap)
+            rec = workload.take_last_sweep()
+            if rec is not None and rec.iterations > 0:
+                a["nbytes"] *= rec.iterations
+                a["measured"] = True
+        workload.note_device_time(comp, "ready", ready.seconds)
+        workload.WORKLOAD.note_batch(
+            comp, "lookup", rec.iterations if rec is not None else None, occ)
 
     def _lookup_batch_extract(self, ctx: dict, retry: bool = False) -> tuple:
         """Phase 2: block on the transfer and materialize per-subject id
         lists; returns (results, suppressed_count).  `retry` marks the
         self-heal re-capture so its suppressions are counted separately
         (never double-counted against the first detection)."""
+        bid = ctx.get("batch_id")
         if ctx.get("all_oracle"):
             # host evaluation outside the lock (reads the live store)
-            with tracing.span("kernel.oracle", kind="lookup_batch"):
+            with tracing.span("kernel.oracle", rows=len(ctx["subjects"])):
                 return [AnnotatedIds(
                             self._oracle.lookup_resources(
                                 ctx["rt"], ctx["perm"], s),
@@ -2972,9 +2997,7 @@ class JaxEndpoint(PermissionsEndpoint):
             # closure-plane batch: read each needed word column of the
             # fragment plane once (columns are shared across subjects)
             (plane, plane_rows), lcols = ctx["leopard"]
-            with tracing.kernel_span("kernel.leopard",
-                                     kind="lookup_batch") as a:
-                a["batch_id"] = ctx.get("batch_id")
+            with tracing.span("kernel.leopard", rows=len(ctx["subjects"])):
                 word_cols = {}
                 for lcol in lcols.values():
                     w = lcol // 32
@@ -2988,13 +3011,11 @@ class JaxEndpoint(PermissionsEndpoint):
                                   & np.uint32(1))[0]
         elif "readback" in ctx:
             # pipelined path: the device already transposed; block on the
-            # waiter future (kernel + transfer timeline slices were
-            # recorded by the waiter thread — this span only attributes
-            # the residual wait to the request trace)
-            with tracing.kernel_span("kernel.wait", kind="lookup_batch") as a:
-                a["batch_id"] = ctx.get("batch_id")
+            # waiter future (the waiter thread marked `ready` and
+            # `transfer`; this span is the finishing thread's residual
+            # wait)
+            with tracing.span("wait", fused="lookup", batch_id=bid):
                 arr = ctx["readback"].result()
-                a["nbytes"] = int(arr.nbytes)
             if arr.dtype == np.uint32:
                 packed_T = arr          # [W, L]: word rows, bit-packed
 
@@ -3007,17 +3028,15 @@ class JaxEndpoint(PermissionsEndpoint):
                     return np.nonzero(bitmap_T[col])[0]
         elif "packed_T" in ctx:
             # the device->host sync point: this blocks until the async
-            # D2H started at capture time lands
-            with tracing.kernel_span("kernel.transfer",
-                                     kind="lookup_batch") as a:
-                a["batch_id"] = ctx.get("batch_id")
-                if not isinstance(ctx["packed_T"], jax.Array):
-                    # the pending result is already a host array (the
-                    # packed kernels sync at capture): the block here is
-                    # the word-transpose copy, not a device transfer —
-                    # tell the timeline so stall attribution stays honest
-                    a["timeline_stage"] = "transpose"
-                packed_T = np.ascontiguousarray(ctx["packed_T"])  # [W, L]
+            # D2H started at capture time lands.  When the pending result
+            # is already a host array (the packed kernels sync at
+            # capture) the block is the word-transpose copy, not a device
+            # transfer, and is marked as such
+            pending = ctx["packed_T"]
+            stage = ("transfer" if isinstance(pending, jax.Array)
+                     else "transpose")
+            with tracing.span(stage, fused="lookup", batch_id=bid) as a:
+                packed_T = np.ascontiguousarray(pending)  # [W, L]
                 a["bucket"] = int(packed_T.shape[0]) * 32
                 a["nbytes"] = int(packed_T.nbytes)
 
@@ -3034,9 +3053,8 @@ class JaxEndpoint(PermissionsEndpoint):
         per_col_ids: dict = {}  # column -> id list (columns are shared)
         out = []
         total_bad = 0
-        t_ext = timeline.now()
-        with tracing.span("kernel.extract", kind="lookup_batch",
-                          batch=len(ctx["subjects"])):
+        with tracing.span("extract", fused="lookup", batch_id=bid,
+                          rows=len(ctx["subjects"])):
             for s in ctx["subjects"]:
                 if s in unknown:
                     out.append(AnnotatedIds(self._oracle.lookup_resources(
@@ -3054,7 +3072,6 @@ class JaxEndpoint(PermissionsEndpoint):
                     per_col_ids[col] = lst = AnnotatedIds(lst,
                                                           source="kernel")
                 out.append(lst)
-        timeline.record("extract", "host", t_ext, batch=ctx.get("batch_id"))
         return out, total_bad
 
     def _lookup_batch_finish_sync(self, ctx: dict) -> list:

@@ -1246,14 +1246,10 @@ class ProxyServer:
             audit=self.audit)
 
         async def authenticated(req: Request) -> Response:
-            from ..utils import timeline
-            if _untraced(req.path):
-                # scrape/health authn stays out of the serving-stage
-                # accounting — it would dominate the histogram counts
-                user = self.authenticator.authenticate(req)
-            else:
-                with timeline.serving_span("authn"):
-                    user = self.authenticator.authenticate(req)
+            # the chain's `authn` span feeds the serving-stage accounting
+            # only inside a request trace, so scrape/health authn (never
+            # traced) stays out of the histogram counts
+            user = self.authenticator.authenticate(req)
             if user is None:
                 return json_response(401, {
                     "kind": "Status", "apiVersion": "v1", "metadata": {},
@@ -1413,8 +1409,8 @@ class ProxyServer:
                 "authz_request_phase_seconds",
                 "Request latency attributed to tracing phases (authn, "
                 "resolve, match, queue_wait, execute, upstream, "
-                "respfilter, workflow, ...)",
-                labels=("phase",))
+                "respfilter, workflow, ...), by request verb",
+                labels=("phase", "verb"))
             tier_latency = REGISTRY.histogram(
                 "authz_tier_seconds",
                 "Per-tier request wall time (router, leader, follower, "
@@ -1489,7 +1485,7 @@ class ProxyServer:
                     self.flight.observe_request(elapsed, resp.status)
                 if phase_latency is not None:
                     for phase, secs in tr.phase_durations().items():
-                        phase_latency.observe(secs, phase=phase)
+                        phase_latency.observe(secs, phase=phase, verb=verb)
                 if (tier_latency is not None
                         and tracing.propagation_enabled()):
                     tier_latency.observe(elapsed, tier=self._tier)
@@ -1537,9 +1533,7 @@ class ProxyServer:
                 up_headers.add(k, v)
             up_req = Request(method=req.method, target=req.target,
                              headers=up_headers, body=req.body)
-            from ..utils import timeline
-            with tracing.span("upstream", phase=True), \
-                    timeline.serving_span("kube_upstream"):
+            with tracing.span("upstream", phase=True):
                 # the kube-apiserver is OUTSIDE the fleet: the internal
                 # X-Authz-* propagation headers must not leak upstream
                 resp = await upstream.round_trip(up_req)  # noqa: A006(external kube hop)

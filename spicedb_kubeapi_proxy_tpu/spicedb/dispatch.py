@@ -122,7 +122,7 @@ def _follow(leader: asyncio.Future, loop) -> asyncio.Future:
 def _activate_batch_trace(waiters: list):
     """Activate the co-batched callers' traces (fanned out) for the
     duration of a fused inner call, so spans the backend records (e.g.
-    jax:// kernel spans) land in EVERY member request's trace.
+    the jax:// dispatch stages) land in EVERY member request's trace.
 
     Always overrides the contextvar — the drain task was created from
     some caller's _kick() and INHERITED that caller's trace context, so

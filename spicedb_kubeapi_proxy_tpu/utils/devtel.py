@@ -16,12 +16,12 @@ numbers every later kernel/sharding PR is judged by:
    `authz_device_bytes{kind=}` within one scrape; a peak-tracking
    high-water mark rides along.
 
-2. **Kernel & compile accounting** (`KernelAccounting`): per-call
-   device time attributed by (span, kind, batch bucket) — fed by
-   `utils/tracing.kernel_span`, which times every kernel span whether
-   or not a request trace is active — plus jit-cache hit/miss/entries
-   per bucket and recompile-storm detection (a bucket recompiling more
-   than N times per window raises a counter and a slow-log line).
+2. **Compile accounting** (`KernelAccounting`): jit-cache
+   hit/miss/entries per bucket and recompile-storm detection (a bucket
+   recompiling more than N times per window raises a counter and a
+   slow-log line).  Per-call stage time is not kept here: the
+   `utils/tracing.span` that marks each stage of a fused call observes
+   it into `authz_dispatch_stage_seconds{stage,kind}`.
 
 3. **Batch-occupancy metrics** (`BatchOccupancy`): useful vs padded
    lanes for every fused batch (the padding waste pow-2 bucketing
@@ -242,14 +242,11 @@ class HbmLedger:
 
 
 class KernelAccounting:
-    """Per-bucket device-time, jit-cache, and recompile-storm counters.
+    """Per-bucket jit-cache and recompile-storm counters.
 
-    `note_kernel_span` is fed by tracing.kernel_span for every kernel
-    span (kernel.device / kernel.dispatch / kernel.transfer / ...),
-    timed around the blocking device sync — per-call device time lands
-    here whether or not the request is traced.  Jit caches register
-    themselves via `track` (weakly, so a dropped graph generation's
-    cache never pins); the entries gauge sums live caches at scrape."""
+    Jit caches register themselves via `track` (weakly, so a dropped
+    graph generation's cache never pins); the entries gauge sums live
+    caches at scrape."""
 
     def __init__(self, registry: Optional[m.Registry] = None):
         registry = registry or m.REGISTRY
@@ -270,16 +267,10 @@ class KernelAccounting:
             "authz_jit_cache_entries",
             "Live jitted entry points across all kernel caches",
             callback=self._count_entries)
-        self._kernel_time = registry.histogram(
-            "authz_kernel_time_seconds",
-            "Per-call device time by kernel span, verb kind, and batch "
-            "bucket (timed around the blocking device sync)",
-            labels=("phase", "kind", "bucket"))
         # cumulative counters for snapshot()/bench artifacts
         self._tot_hits = 0
         self._tot_misses = 0
         self._tot_storms = 0
-        self._time_by_bucket: dict = {}     # bucket -> seconds
         self._compiles: dict = {}           # bucket -> deque[timestamps]
         self._caches: list = []             # weakrefs to tracked caches
 
@@ -337,27 +328,11 @@ class KernelAccounting:
                 "the kernel cache", bucket, STORM_THRESHOLD + 1,
                 STORM_WINDOW_S, STORM_THRESHOLD)
 
-    # -- per-call device time ------------------------------------------------
-
-    def note_kernel_span(self, name: str, attrs: dict,
-                         seconds: float) -> None:
-        if not enabled():
-            return
-        kind = str(attrs.get("kind", ""))
-        bucket = attrs.get("bucket", "")
-        self._kernel_time.observe(seconds, phase=name, kind=kind,
-                                  bucket=str(bucket))
-        if bucket != "":
-            with self._lock:
-                self._time_by_bucket[str(bucket)] = (
-                    self._time_by_bucket.get(str(bucket), 0.0) + seconds)
-
     def snapshot(self) -> dict:
         with self._lock:
             return {"hits": self._tot_hits, "misses": self._tot_misses,
                     "storms": self._tot_storms,
-                    "entries": int(self._count_entries_locked()),
-                    "time_by_bucket_s": dict(self._time_by_bucket)}
+                    "entries": int(self._count_entries_locked())}
 
     def _count_entries_locked(self) -> int:
         n = 0
@@ -497,18 +472,6 @@ def next_generation() -> int:
         return _gen_counter
 
 
-def note_kernel_span(name: str, attrs: dict, seconds: float) -> None:
-    """Hook target for tracing.kernel_span (lazy-bound there)."""
-    KERNELS.note_kernel_span(name, attrs, seconds)
-    comp = attrs.get("workload")
-    if comp:
-        # same wall-clock seconds that feed authz_kernel_time_seconds —
-        # /debug/workload reconciles against that cumulative sum by
-        # construction (utils/workload.py splits by row share)
-        from . import workload
-        workload.note_device_time(comp, name, seconds)
-
-
 def snapshot() -> dict:
     """One flat device-telemetry snapshot (cumulative counters + current
     gauges) — bench artifacts embed the per-config diff of two of these."""
@@ -528,10 +491,6 @@ def diff_snapshot(before: dict, after: dict) -> dict:
     b_o, a_o = before["occupancy"], after["occupancy"]
     useful = a_o["useful"] - b_o["useful"]
     padded = a_o["padded"] - b_o["padded"]
-    time_by_bucket = {
-        k: round(v - b_j["time_by_bucket_s"].get(k, 0.0), 4)
-        for k, v in a_j["time_by_bucket_s"].items()
-        if v - b_j["time_by_bucket_s"].get(k, 0.0) > 0}
     return {
         "hbm_bytes": after["hbm_bytes"],
         "hbm_total_bytes": after["hbm_total_bytes"],
@@ -544,7 +503,6 @@ def diff_snapshot(before: dict, after: dict) -> dict:
         "mean_batch_occupancy": (round(useful / (useful + padded), 4)
                                  if useful + padded else None),
         "collapsed_duplicates": a_o["collapsed"] - b_o["collapsed"],
-        "kernel_time_by_bucket_s": time_by_bucket,
     }
 
 
@@ -728,18 +686,25 @@ class FlightRecorder:
 
         # per-window deltas (phase histograms only record traced
         # requests, so they carry no probe/scrape dilution)
-        phase_delta = _delta_counts(raw["phase"], prev.get("phase", {}))
+        # per-phase deltas, summed over the histogram's other labels
+        # (the request verb)
+        phase_delta: dict = {}
+        for key, counts in _delta_counts(raw["phase"],
+                                         prev.get("phase", {})).items():
+            name = key[0] if key else ""
+            acc = phase_delta.get(name)
+            phase_delta[name] = (counts if acc is None
+                                 else [a + c for a, c in zip(acc, counts)])
         p_hits, p_misses = prev.get("cache", (0, 0))
         d_hits = raw["cache"][0] - p_hits
         d_misses = raw["cache"][1] - p_misses
         requests, errors, lats, tallies = self._drain_intake()
 
         phases = {}
-        for key, counts in phase_delta.items():
+        for name, counts in phase_delta.items():
             n = sum(counts)
             if not n:
                 continue
-            name = key[0] if key else ""
             phases[name] = {
                 "count": n,
                 "p50_ms": _ms(_quantile_from_counts(phase_buckets, counts,
@@ -767,8 +732,7 @@ class FlightRecorder:
             "hbm": {"by_kind": LEDGER.totals(), "total": LEDGER.total(),
                     "peak": LEDGER.peak},
             "occupancy": OCCUPANCY.snapshot(),
-            "jit": {k: v for k, v in KERNELS.snapshot().items()
-                    if k != "time_by_bucket_s"},
+            "jit": KERNELS.snapshot(),
             # window evidence links: the slowest traces that STARTED in
             # this window (ids resolve at /debug/traces) and the
             # dispatch-timeline condensate for the same interval

@@ -35,6 +35,8 @@ import asyncio
 import re
 from typing import Iterable, Optional
 
+from .timeline import SERVING_SPANS
+
 # /metrics lines worth lifting into the merged view (full scrape text is
 # deliberately NOT echoed back — the merge is a roll-up, not a mirror)
 _SKEW_RE = re.compile(
@@ -256,12 +258,11 @@ def assemble_trace(segments: list) -> dict:
         ti["self_ms"] += max(0.0, dur - child_hops_ms)
         ti["segments"] += 1
         for sp in trd.get("spans") or []:
-            name = sp.get("name") or ""
-            if name.startswith("serving."):
+            stage = SERVING_SPANS.get(sp.get("name") or "")
+            if stage is not None:
                 st = stages.setdefault(tier, {})
-                st[name[len("serving."):]] = round(
-                    st.get(name[len("serving."):], 0.0)
-                    + float(sp.get("duration_ms") or 0.0), 3)
+                st[stage] = round(st.get(stage, 0.0)
+                                  + float(sp.get("duration_ms") or 0.0), 3)
     root_ms = float(root_trd.get("duration_ms") or 0.0)
     attributed = sum(t["self_ms"] for t in tiers.values()) + network_ms
     return {

@@ -11,22 +11,24 @@ roofline gap (ROADMAP item 1: `transfer_transpose_ms` > device time at
 This module is the dependency-free instrument:
 
 - **Event ring**: a bounded ring of monotonic-clock `TimelineEvent`s
-  emitted from every stage of the batch pipeline — host pack,
-  transpose, host→device transfer / blocking sync, kernel launch,
-  device→host extract, graph rebuild/compact/warm-start spans, and jit
-  compiles — each carrying the recording thread id, fused-batch id,
-  pow-2 lane bucket, and bytes moved.  Device-side kernel spans arrive
-  through `utils/tracing.kernel_span` (lazy-bound hook, mirroring the
-  devtel kernel accounting); host/dispatcher/rebuild stages record
-  directly.
+  emitted from every stage of the batch pipeline — host pack, the
+  device window (`kernel`), device→host transfer / transpose, host
+  extract, the serving stages, graph rebuild/compact/warm-start spans,
+  and jit compiles — each carrying the recording thread id, fused-batch
+  id, pow-2 lane bucket, and bytes moved.  Stages the program marks
+  with `utils/tracing.span` arrive from the span's exit (`note_span`
+  maps span names to stages and tracks); dispatcher and rebuild slices
+  record directly.
 
 - **Derived telemetry** per dispatch: achieved bytes/sec per stage
   (`authz_dispatch_bandwidth_bytes_per_sec{stage=}`), the kernel-stage
   bandwidth as a fraction of the configured device HBM peak
   (`authz_roofline_fraction`; `--device-hbm-peak-gbps`, defaulting to
-  the device kind's published peak), host-stall attribution
-  (`authz_dispatch_stall_seconds{cause=pack|transpose|transfer|rebuild|compile}`),
-  and the transfer/compute **overlap ratio** — the fraction of
+  the device kind's published peak), host-stall attribution in
+  `summary()["stall_s"]` (pack, transpose, transfer, rebuild, compile;
+  rebuild and compile slices are also observed into
+  `authz_dispatch_stage_seconds`, the family the dispatch stage spans
+  feed), and the transfer/compute **overlap ratio** — the fraction of
   transfer/transpose wall time during which a *different* batch's
   kernel interval was open (`authz_dispatch_overlap_ratio`).  The
   overlap ratio is the direct before/after number for double-buffered
@@ -72,6 +74,7 @@ import time
 from typing import Iterable, Optional
 
 from . import metrics as m
+from .tracing import stage_histogram
 
 # host-stall attribution: stage -> stall cause.  compact/warm_start are
 # rebuild-family stalls (they hold the same endpoint lock a rebuild
@@ -119,18 +122,22 @@ _SERVING_STAGES = ("authn", "rule_match", "kube_upstream", "decode",
 _TRACK_TIDS = {"host": 1, "dispatcher": 2, "device": 3, "rebuild": 4,
                "serving": 5}
 
-# tracing.kernel_span name -> timeline stage.  kernel.dispatch maps to
-# "kernel": with the current packed-extraction path the capture-side
-# call blocks until the device result lands, so its host window IS the
-# kernel execution; on a truly async backend it degrades to launch-only
-# (still the honest lower bound).  Spans may override per call via
-# attrs["timeline_stage"] (e.g. kernel.transfer flips to "transpose"
-# when the pending result is already a host array and the block is the
-# word-transpose copy, not a device sync).
-_KERNEL_SPAN_STAGES = {
-    "kernel.device": "kernel",
-    "kernel.dispatch": "kernel",
-    "kernel.transfer": "transfer",
+# tracing.span name -> serving stage (the request spans that mark them)
+SERVING_SPANS = {"authn": "authn", "match": "rule_match",
+                 "upstream": "kube_upstream", "decode": "decode",
+                 "filter": "filter", "serialize": "serialize"}
+
+# tracing.span name -> (timeline stage, track).  `ready` is the device
+# window of a fused call (launch end -> result ready; the whole blocking
+# call on the serial entry points), so it is the timeline's `kernel`.
+# Unmapped span names stay out of the ring.
+_SPAN_STAGES = {
+    "pack": ("pack", "host"),
+    "ready": ("kernel", "device"),
+    "transfer": ("transfer", "device"),
+    "transpose": ("transpose", "device"),
+    "extract": ("extract", "host"),
+    **{name: (stage, "serving") for name, stage in SERVING_SPANS.items()},
 }
 
 
@@ -298,46 +305,6 @@ class _Span:
         return False
 
 
-_trace_current = None  # resolved lazily; False => tracing unavailable
-
-
-def _note_trace_span(stage: str, start: float, end: float) -> None:
-    """Mirror a serving-stage span into the active request trace (as a
-    forensic `serving.<stage>` span, never a phase — the phases already
-    tile the wall time).  This is what lets the fleet merge attribute
-    serving stages per tier: the timeline ring is process-wide, but the
-    trace travels with the request.  Lazy-bound, same discipline as the
-    tracing->timeline hook in the other direction."""
-    global _trace_current
-    if _trace_current is None:
-        try:
-            from .tracing import current_trace
-            _trace_current = current_trace  # noqa: A004(import cache, not gated state)
-        except Exception:
-            _trace_current = False  # noqa: A004(import cache, not gated state)
-    if _trace_current:
-        tr = _trace_current()
-        if tr is not None:
-            try:
-                tr.add_span("serving." + stage, start, end)
-            except Exception:  # pragma: no cover - defensive
-                pass
-
-
-class _ServingSpan(_Span):  # noqa: A004(built behind gate)
-    """Serving-track span: records the timeline event, feeds the
-    per-stage serving histogram, and mirrors into the request trace in
-    one exit."""
-    __slots__ = ()
-
-    def __exit__(self, *exc):
-        end = time.perf_counter()
-        self._tl.record(self.stage, self.track, self.t0, end, **self.kw)
-        self._tl._serving.observe(end - self.t0, stage=self.stage)
-        _note_trace_span(self.stage, self.t0, end)
-        return False
-
-
 class Timeline:
     """Bounded event ring + derived dispatch telemetry (module singleton
     `TIMELINE`; an isolated instance is constructible for tests)."""
@@ -368,11 +335,7 @@ class Timeline:
         self._stall_s: dict = {}
         self._bytes_by_stage: dict = {}
         self._events_total = 0
-        self._stall = registry.counter(
-            "authz_dispatch_stall_seconds",
-            "Host wall time stalled per dispatch-pipeline cause "
-            "(pack, transpose, transfer, rebuild, compile)",
-            labels=("cause",))
+        self._stage = stage_histogram(registry)
         self._bw = registry.gauge(
             "authz_dispatch_bandwidth_bytes_per_sec",
             "Achieved bytes/sec of the most recent dispatch-pipeline "
@@ -469,16 +432,43 @@ class Timeline:
                     self._bytes_by_stage.get(stage, 0) + int(nbytes))
             if cause is not None and dur > 0:
                 self._stall_s[cause] = self._stall_s.get(cause, 0.0) + dur
-        if cause is not None and dur > 0:
-            self._stall.inc(dur, cause=cause)
-        if (nbytes and dur > 0 and stage in _BANDWIDTH_STAGES
+        if cause in ("rebuild", "compile") and dur > 0:
+            # no span marks these stalls: the ring observes them into
+            # authz_dispatch_stage_seconds itself (the span of every
+            # other stage of that family observes its own)
+            self._stage.observe(dur, stage=cause, kind=attrs.get("kind", ""))
+        self._set_bandwidth(ev)
+
+    def _set_bandwidth(self, ev: TimelineEvent) -> None:
+        dur = ev.duration
+        if (ev.nbytes and dur > 0 and ev.stage in _BANDWIDTH_STAGES
                 and not (ev.attrs and ev.attrs.get("compile"))):
-            bw = nbytes / dur
-            self._bw.set(bw, stage=stage)
-            if stage in _COMPUTE_STAGES:
+            bw = ev.nbytes / dur
+            self._bw.set(bw, stage=ev.stage)
+            if ev.stage in _COMPUTE_STAGES:
                 peak = self.hbm_peak_bytes_per_s()
                 if peak:
                     self._roofline.set(bw / peak)
+
+    def retag_kernel(self, batch: int, nbytes: int) -> None:
+        """Upgrade the byte tag of batch `batch`'s recorded `kernel`
+        slice from the modeled one-sweep floor to measured bytes
+        (iterations x one-sweep traffic, known only once the sweep trace
+        is read back, after the slice has closed)."""
+        if not enabled():
+            return
+        with self._lock:
+            for ev in reversed(self._ring):
+                if ev.stage == "kernel" and ev.batch == batch:
+                    break
+            else:
+                return
+            self._bytes_by_stage["kernel"] = (
+                self._bytes_by_stage.get("kernel", 0)
+                + int(nbytes) - ev.nbytes)
+            ev.nbytes = int(nbytes)
+            ev.attrs = dict(ev.attrs or {}, measured=True)
+        self._set_bandwidth(ev)
 
     def _compile_overlaps(self, start: float) -> bool:
         """True when a recently recorded compile slice overlaps a span
@@ -500,16 +490,6 @@ class Timeline:
         if not enabled():
             return _NULL_SPAN
         return _Span(self, stage, track, kw)
-
-    def serving_span(self, stage: str, **kw):
-        """Span on the serving track (authn, rule_match, kube_upstream,
-        decode, filter, serialize): the timeline event rides the normal
-        ring/chrome-trace machinery AND the duration feeds the
-        authz_serving_stage_seconds{stage=} histogram.  Same gate-off
-        contract as span(): the shared null context, nothing ticks."""
-        if not enabled():
-            return _NULL_SPAN
-        return _ServingSpan(self, stage, "serving", kw)
 
     def time_first_call(self, fn, bucket: Optional[int] = None,
                         stage: str = "compile", track: str = "device",
@@ -641,10 +621,11 @@ class Timeline:
             # overlap-aware stall attribution (device-resident
             # pipeline): transfer/transpose wall time hidden behind a
             # DIFFERENT batch's kernel window is not a stall — the
-            # device never went idle for it.  The cumulative
-            # authz_dispatch_stall_seconds counter stays raw wall time
-            # (it is incremented at record time, before any overlap is
-            # knowable); this window condensate is the judgment number.
+            # device never went idle for it.  The per-call stage
+            # histogram (authz_dispatch_stage_seconds) stays raw wall
+            # time (observed as each stage closes, before any overlap
+            # is knowable); this window condensate is the judgment
+            # number.
             for stage, hid in ov["hidden_s_by_stage"].items():
                 cause = _STALL_CAUSE.get(stage)
                 if cause in stalls:
@@ -735,10 +716,6 @@ def span(stage: str, track: str, **kw):
     return TIMELINE.span(stage, track, **kw)
 
 
-def serving_span(stage: str, **kw):
-    return TIMELINE.serving_span(stage, **kw)
-
-
 def time_first_call(fn, bucket: Optional[int] = None,
                     static_args: int = 0, shape_args: bool = False):
     return TIMELINE.time_first_call(fn, bucket=bucket,
@@ -758,21 +735,23 @@ def chrome_trace(since: Optional[float] = None) -> dict:
     return TIMELINE.chrome_trace(since)
 
 
-def note_kernel_span(name: str, attrs: dict, start: float,
-                     end: float) -> None:
-    """Hook target for tracing.kernel_span (lazy-bound there): device
-    kernel spans land on the timeline's device track without the
-    endpoint emitting them twice.  Callers may override the stage per
-    call via attrs['timeline_stage']."""
-    stage = attrs.get("timeline_stage") or _KERNEL_SPAN_STAGES.get(name)
-    if stage is None:
+def note_span(name: str, attrs: dict, start: float, end: float) -> None:
+    """Hook target for tracing.span's exit (lazy-bound there): a span
+    whose name maps to a timeline stage lands in the ring, tagged from
+    its attrs (batch_id, bucket, nbytes, measured); a serving stage also
+    feeds authz_serving_stage_seconds."""
+    mapped = _SPAN_STAGES.get(name)
+    if mapped is None or not enabled():
         return
+    stage, track = mapped
     extra = {}
     if attrs.get("measured"):
         # byte tag upgraded from the modeled one-sweep floor to measured
         # iterations x per-sweep bytes (KernelIntrospect sweep telemetry)
         extra["measured"] = True
-    TIMELINE.record(stage, "device", start, end,
+    TIMELINE.record(stage, track, start, end,
                     batch=attrs.get("batch_id"),
                     bucket=attrs.get("bucket") or None,
                     nbytes=int(attrs.get("nbytes", 0) or 0), **extra)
+    if track == "serving":
+        TIMELINE._serving.observe(end - start, stage=stage)

@@ -20,19 +20,25 @@ is the dependency-free tracing core that makes the gap attributable:
 - Spans marked `phase=True` are the request's latency attribution: they
   are chosen to tile the request wall time without overlapping (authn,
   resolve, match, queue_wait, execute, upstream, respfilter, workflow),
-  feed the `authz_request_phase_seconds{phase=...}` histogram, and sum
-  to ~wall time.  Unmarked spans (kernel.device, kernel.transfer,
-  workflow.<activity>, ...) are forensic detail and may overlap phases.
+  feed the `authz_request_phase_seconds{phase=...,verb=...}` histogram,
+  and sum to ~wall time.  Unmarked spans (decode, filter, serialize,
+  kernel.oracle, workflow.<activity>, ...) are forensic detail and may
+  overlap phases.
+
+- `span` is the one primitive that marks a stage.  Besides recording
+  into the active trace it enters `jax.profiler.TraceAnnotation`, so a
+  profile captured with `jax.profiler.trace` carries the program's stage
+  names on the profiler's own clock (the jax import is never triggered
+  here: the annotation binds once jax is loaded).  A span given
+  `fused=<kind>` is a stage of one fused device call (`DISPATCH_STAGES`)
+  and is observed once per call into
+  `authz_dispatch_stage_seconds{stage,kind}`, traced or not.  Span exits
+  also feed the dispatch timeline ring (utils/timeline.py).
 
 - `SlowTraceRecorder`: a bounded recorder retaining the N slowest
   traces, served at the authenticated `/debug/traces` endpoint and
   drained per window by scripts/soak.py so a soak run explains its own
   p99 spikes.
-
-- `kernel_span`: a span that additionally enters
-  `jax.profiler.TraceAnnotation`, so device timelines captured with
-  `jax.profiler.trace` carry the proxy's phase names.  The jax import
-  is lazy and optional — this module stays dependency-free.
 
 Thread-safe: spans are recorded from asyncio handlers and executor
 threads concurrently.
@@ -43,10 +49,13 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import heapq
+import sys
 import threading
 import time
 import uuid
 from typing import Iterable, Optional
+
+from . import metrics as m
 
 TRACE_ID_HEADER = "X-Trace-Id"
 
@@ -90,8 +99,8 @@ class Span:
 
 class SpanSink:
     """Anything spans can be recorded into (Trace or FanoutTrace).
-    Record via the module-level span()/kernel_span() context managers or
-    add_span() directly."""
+    Record via the module-level span() context manager or add_span()
+    directly."""
 
     def add_span(self, name: str, start: float, end: float,
                  phase: bool = False, **attrs) -> None:
@@ -158,8 +167,8 @@ class Trace(SpanSink):
 
 class FanoutTrace(SpanSink):
     """Multiplexes span records to several traces: the dispatch drain
-    loop activates one of these around a fused inner call so kernel
-    spans land in EVERY co-batched request's trace."""
+    loop activates one of these around a fused inner call so the call's
+    stage spans land in EVERY co-batched request's trace."""
 
     def __init__(self, traces: Iterable[SpanSink]):
         self.traces = tuple(traces)
@@ -207,20 +216,124 @@ def request_trace(trace_id: Optional[str] = None, **attrs):
         _current.reset(token)
 
 
-@contextlib.contextmanager
-def span(name: str, phase: bool = False, **attrs):
-    """Record a span into the active trace; no-op (near-zero cost) when
-    tracing is inactive.  Yields the attrs dict so callers can enrich it
-    before the span closes."""
-    tr = _current.get()
-    if tr is None:
-        yield attrs
-        return
-    t0 = time.perf_counter()
-    try:
-        yield attrs
-    finally:
-        tr.add_span(name, t0, time.perf_counter(), phase=phase, **attrs)
+# the stages that tile one fused device call, in order (docs/
+# observability.md "Dispatch stages"): host packing, the jitted call's
+# async dispatch, launch end -> device result ready (device-stream
+# queueing plus the call's own kernel), ready -> host array in hand,
+# the finishing thread blocked on the readback, and the host-side id
+# extraction (lookups) or result assembly (checks)
+DISPATCH_STAGES = ("pack", "launch", "ready", "transfer", "wait",
+                   "extract", "assemble")
+
+
+def stage_histogram(registry: Optional[m.Registry] = None) -> m.Histogram:
+    """`authz_dispatch_stage_seconds{stage,kind}`: one observation per
+    fused call per stage (kind lookup/check; "" for a stall no call
+    owns, such as a background rebuild)."""
+    return (registry or m.REGISTRY).histogram(
+        "authz_dispatch_stage_seconds",
+        "Seconds per stage of one fused device call (pack, launch, ready, "
+        "transfer, wait, extract, assemble) and per dispatch stall "
+        "(transpose, rebuild, compile), by call kind",
+        labels=("stage", "kind"))
+
+
+_STAGE_SECONDS = stage_histogram()
+
+_trace_me = None  # jax.profiler.TraceAnnotation once jax is loaded
+
+
+def _annotation(name: str):
+    """A profiler TraceMe for `name`, or None while jax is not loaded
+    (no profile session can be active then, and a span must never pay
+    for importing jax)."""
+    global _trace_me
+    if _trace_me is None:
+        if "jax" not in sys.modules:
+            return None
+        try:
+            from jax.profiler import TraceAnnotation
+            _trace_me = TraceAnnotation
+        except Exception:
+            _trace_me = False
+    return _trace_me(name) if _trace_me else None
+
+
+_timeline_note = None  # resolved lazily; False => timeline unavailable
+
+
+def _note_timeline(name: str, attrs: dict, start: float, end: float) -> None:
+    """Feed a closed span into the dispatch timeline ring (utils/
+    timeline.py maps span names to its stages and tracks) — lazy-bound
+    so this module keeps no import of the timeline."""
+    global _timeline_note
+    if _timeline_note is None:
+        try:
+            from .timeline import note_span
+            _timeline_note = note_span
+        except Exception:
+            _timeline_note = False
+    if _timeline_note:
+        try:
+            _timeline_note(name, attrs, start, end)
+        except Exception:
+            pass
+
+
+class _SpanScope:
+    """Context manager behind span(); `.seconds` holds the duration once
+    the block has closed."""
+
+    __slots__ = ("name", "phase", "fused", "attrs", "start", "seconds",
+                 "_trace", "_ann")
+
+    def __init__(self, name: str, phase: bool, fused: Optional[str],
+                 attrs: dict):
+        self.name = name
+        self.phase = phase
+        self.fused = fused
+        self.attrs = attrs
+        self.seconds = 0.0
+
+    def __enter__(self) -> dict:
+        self._trace = _current.get()
+        self._ann = _annotation(self.name)
+        if self._ann is not None:
+            self._ann.__enter__()
+        self.start = time.perf_counter()
+        return self.attrs
+
+    def __exit__(self, *exc) -> bool:
+        end = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        self.seconds = end - self.start
+        tr = self._trace
+        if tr is not None:
+            tr.add_span(self.name, self.start, end, phase=self.phase,
+                        **self.attrs)
+        if self.fused is not None:
+            _STAGE_SECONDS.observe(self.seconds, stage=self.name,
+                                   kind=self.fused)
+        if tr is not None or self.fused is not None:
+            _note_timeline(self.name, self.attrs, self.start, end)
+        return False
+
+
+def span(name: str, phase: bool = False, fused: Optional[str] = None,
+         **attrs) -> _SpanScope:
+    """Mark the enclosed block as stage `name`: recorded into the active
+    trace (fanned out to every co-batched trace inside a fused call),
+    annotated on the profiler's host plane, and fed to the timeline ring.
+    `fused` (the call kind, "lookup" or "check") makes it a stage of one
+    fused device call, observed once per call into
+    authz_dispatch_stage_seconds whether or not a request is traced.
+    Yields the attrs dict so callers can enrich it before the span
+    closes.  Without an active trace and without `fused`, only the
+    annotation remains (a no-op outside a profile session)."""
+    if fused is not None:
+        attrs["kind"] = fused
+    return _SpanScope(name, phase, fused, attrs)
 
 
 def clean_trace_id(raw: str) -> Optional[str]:
@@ -337,101 +450,6 @@ def hop_span(name: str, tier: str = "", **attrs):
     finally:
         tr.add_span(name, t0, time.perf_counter(), span_id=span_id,
                     **attrs)
-
-
-# -- TPU profiler bridge -----------------------------------------------------
-
-_jax_annotation = None  # resolved lazily; False => jax unavailable
-
-
-def _profiler_annotation(name: str):
-    global _jax_annotation
-    if _jax_annotation is None:
-        try:
-            from jax.profiler import TraceAnnotation
-            _jax_annotation = TraceAnnotation
-        except Exception:
-            _jax_annotation = False
-    if _jax_annotation:
-        return _jax_annotation(name)
-    return contextlib.nullcontext()
-
-
-_devtel_note = None  # resolved lazily; False => devtel unavailable
-
-
-def _note_kernel(name: str, attrs: dict, seconds: float) -> None:
-    """Feed per-call device time into the device-telemetry kernel
-    accounting (utils/devtel.py) — lazy-bound so this module keeps no
-    hard intra-package dependency and stays importable standalone."""
-    global _devtel_note
-    if _devtel_note is None:
-        try:
-            from .devtel import note_kernel_span
-            _devtel_note = note_kernel_span
-        except Exception:
-            _devtel_note = False
-    if _devtel_note:
-        try:
-            _devtel_note(name, attrs, seconds)
-        except Exception:
-            pass
-
-
-def note_device_window(name: str, attrs: dict, seconds: float) -> None:
-    """Public entry for async-readback waiters (ops/jax_endpoint.py):
-    under the pipelined dispatch path the dispatching call is
-    launch-only, so the true device window is only measurable by the
-    thread parked on the completed future — it feeds the measured
-    window into the kernel accounting here (the waiter records its own
-    timeline events; this covers only the devtel histograms)."""
-    _note_kernel(name, attrs, seconds)
-
-
-_timeline_note = None  # resolved lazily; False => timeline unavailable
-
-
-def _note_timeline(name: str, attrs: dict, start: float, end: float) -> None:
-    """Feed kernel spans (with their start/end instants, not just the
-    duration) into the dispatch timeline (utils/timeline.py) so device
-    slices land on the timeline's device track — same lazy-binding
-    discipline as the devtel hook above."""
-    global _timeline_note
-    if _timeline_note is None:
-        try:
-            from .timeline import note_kernel_span
-            _timeline_note = note_kernel_span
-        except Exception:
-            _timeline_note = False
-    if _timeline_note:
-        try:
-            _timeline_note(name, attrs, start, end)
-        except Exception:
-            pass
-
-
-@contextlib.contextmanager
-def kernel_span(name: str, phase: bool = False, **attrs):
-    """Span + `jax.profiler.TraceAnnotation`: when a jax profiler trace
-    is active the device timeline carries the proxy's span names, so a
-    TPU profile aligns 1:1 with the request trace.
-
-    Also the device-time attribution point: the block is timed even with
-    no active request trace (the direct bench path) and the duration is
-    recorded into the kernel-accounting histograms
-    (`authz_kernel_time_seconds{phase=,kind=,bucket=}`) keyed by the
-    span's attrs — callers may enrich the yielded attrs dict (e.g. set
-    `bucket`) before the block closes."""
-    a = attrs
-    t0 = time.perf_counter()
-    try:
-        with span(name, phase=phase, **attrs) as a:
-            with _profiler_annotation(name):
-                yield a
-    finally:
-        t1 = time.perf_counter()
-        _note_kernel(name, a, t1 - t0)
-        _note_timeline(name, a, t0, t1)
 
 
 # -- slow-trace retention ----------------------------------------------------

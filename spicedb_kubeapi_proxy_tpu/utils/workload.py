@@ -16,12 +16,12 @@ to the host oracle.  It is fed from three places:
    `authz_frontier_decay{kernel,verb}` (successive-iteration frontier
    ratios: mass near 0 = fast convergence, mass near 1 = deep nesting).
 
-2. **Device-time attribution** (`note_device_time`): the devtel
-   kernel-span hook forwards the SAME seconds that feed
-   `authz_kernel_time_seconds{phase=kernel.device|kernel.dispatch}`,
-   along with the batch's (type, permission, rows) composition stamped
-   on the span attrs — so the per-pair rows sum-reconcile with the
-   cumulative histogram by construction.
+2. **Device-time attribution** (`note_device_time`): the endpoint
+   forwards the seconds of each fused call's `ready` span — the SAME
+   seconds that span observes into
+   `authz_dispatch_stage_seconds{stage="ready"}` — along with the
+   batch's (type, permission, rows) composition, so the per-pair rows
+   sum-reconcile with the cumulative histogram by construction.
 
 3. **Routing & cache hooks** (`note_batch` / `note_oracle` /
    `note_cache`): batch occupancy and measured sweep depth per pair,
@@ -58,9 +58,10 @@ _ITER_BUCKETS = (1, 2, 3, 4, 5, 6, 8, 10, 12, 16, 24, 32)
 _DECAY_BUCKETS = (0.05, 0.1, 0.2, 0.3, 0.4, 0.5, 0.6, 0.7, 0.8, 0.9,
                   1.0, 2.0)
 
-# kernel-span phases that represent the device window — the phases whose
-# authz_kernel_time_seconds observations the per-pair rows reconcile with
-DEVICE_PHASES = frozenset(("kernel.device", "kernel.dispatch"))
+# dispatch stages that represent the device window — the stages whose
+# authz_dispatch_stage_seconds observations the per-pair rows reconcile
+# with
+DEVICE_PHASES = frozenset(("ready",))
 
 
 def enabled() -> bool:
@@ -139,7 +140,7 @@ class WorkloadAccounting:
     def take_last_sweep(self) -> Optional[SweepRecord]:
         """Pop the calling thread's most recent SweepRecord (the serial
         kernel wrappers run synchronously on the caller's thread, so the
-        endpoint can patch measured bytes onto its open kernel span)."""
+        endpoint can patch measured bytes onto its open `ready` span)."""
         rec = getattr(self._tls, "last", None)
         self._tls.last = None
         return rec
@@ -401,7 +402,7 @@ def note_device_time(comp, phase: str, seconds: float) -> None:
 
 def comp_rows(reqs: Sequence) -> list:
     """Collapse a CheckRequest sequence into the (type, permission, rows)
-    composition stamped on kernel spans."""
+    composition the endpoint attributes device time by."""
     agg: dict = {}
     for r in reqs:
         pair = (r.resource.type, r.permission)

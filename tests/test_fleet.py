@@ -204,9 +204,9 @@ class TestMergeUnit:
 
     def test_serving_stage_rollup_per_tier(self):
         child_spans = [
-            {"name": "serving.decode", "start_ms": 0.5,
+            {"name": "decode", "start_ms": 0.5,
              "duration_ms": 3.0},
-            {"name": "serving.filter", "start_ms": 3.5,
+            {"name": "filter", "start_ms": 3.5,
              "duration_ms": 2.0},
             {"name": "match", "start_ms": 0.0, "duration_ms": 1.0,
              "phase": True},
@@ -216,8 +216,9 @@ class TestMergeUnit:
                     spans=child_spans)
         a = fleet.assemble_trace([(member("r", []), root),
                                   (member("s0", []), child)])
+        # `match` is the span that marks the rule_match serving stage
         assert a["serving_stages_ms"]["leader"] == {
-            "decode": 3.0, "filter": 2.0}
+            "decode": 3.0, "filter": 2.0, "rule_match": 1.0}
 
     def test_merge_dedupes_and_drops_single_process(self):
         root = seg(TID, "router", 12.0, spans=[hop(HOP_A, 2.0, 8.0)])
@@ -379,7 +380,7 @@ class TestRouterContinuity:
             # the leader recorded serving-stage spans inside the trace
             stage_names = {sp["name"]
                            for sp in by_tier["leader"]["spans"]}
-            assert "serving.authn" in stage_names
+            assert "authn" in stage_names
 
         asyncio.run(go())
 

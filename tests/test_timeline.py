@@ -216,9 +216,20 @@ class TestDerivedTelemetry:
         assert s["stall_s"]["rebuild"] == pytest.approx(7.0)
         assert s["stall_s"]["compile"] == pytest.approx(0.125)
         assert "kernel" not in s["stall_s"]
-        c = reg.get("authz_dispatch_stall_seconds")
-        assert c.value(cause="rebuild") == pytest.approx(7.0)
-        assert c.value(cause="pack") == pytest.approx(0.5)
+        # the ring itself observes only the stages no span marks
+        # (rebuild family, compile) into authz_dispatch_stage_seconds
+        h = reg.get("authz_dispatch_stage_seconds")
+        raw = h.raw()
+        assert raw[("rebuild", "")][1:] == (pytest.approx(7.0), 3)
+        assert raw[("compile", "")][1:] == (pytest.approx(0.125), 1)
+        assert ("pack", "") not in raw and ("kernel", "") not in raw
+        # pack (like every stage of a fused call) is observed once by
+        # the span that marks it, with the call's kind
+        glob = m.REGISTRY.get("authz_dispatch_stage_seconds")
+        before = glob.count(stage="pack", kind="check")
+        with tracing.span("pack", fused="check"):
+            pass
+        assert glob.count(stage="pack", kind="check") == before + 1
 
     def test_bandwidth_and_roofline(self):
         tl = make_timeline(capacity=32, hbm_peak_gbps=1.0)  # 1 GB/s peak
@@ -318,14 +329,13 @@ class TestDerivedTelemetry:
         assert 'stage="rebuild"' not in "\n".join(g.render())
 
 
-# -- the tracing.kernel_span hook --------------------------------------------
+# -- the tracing.span hook ---------------------------------------------------
 
 
 class TestKernelSpanHook:
     def test_kernel_span_lands_on_device_track(self):
         mark = timeline.now()
-        with tracing.kernel_span("kernel.device", kind="check",
-                                 bucket=64) as a:
+        with tracing.span("ready", fused="check", bucket=64) as a:
             a["batch_id"] = 424242
             a["nbytes"] = 4096
         evs = [e for e in timeline.TIMELINE.events(since=mark)
@@ -335,9 +345,10 @@ class TestKernelSpanHook:
         assert evs[0].nbytes == 4096 and evs[0].bucket == 64
 
     def test_timeline_stage_override(self):
+        # a host word-transpose is marked by its own span name and lands
+        # as the timeline's transpose stage, not as a transfer
         mark = timeline.now()
-        with tracing.kernel_span("kernel.transfer", kind="lookup") as a:
-            a["timeline_stage"] = "transpose"
+        with tracing.span("transpose", fused="lookup") as a:
             a["batch_id"] = 434343
         evs = [e for e in timeline.TIMELINE.events(since=mark)
                if e.batch == 434343]
@@ -345,8 +356,9 @@ class TestKernelSpanHook:
 
     def test_unmapped_kernel_span_is_ignored(self):
         mark = timeline.now()
-        with tracing.kernel_span("kernel.oracle", kind="check"):
-            pass
+        with tracing.request_trace():
+            with tracing.span("kernel.oracle", rows=1):
+                pass
         assert [e for e in timeline.TIMELINE.events(since=mark)
                 if e.stage == "kernel.oracle"] == []
 

@@ -255,7 +255,8 @@ def test_e2e_jax_trace_covers_all_phases_and_tiles_wall_time():
             names = {s["name"] for s in tr["spans"]}
             assert {"authn", "match", "queue_wait", "execute",
                     "respfilter"} <= names
-            assert any(n.startswith("kernel.") for n in names), names
+            # the fused check's stages fan out into the request trace
+            assert {"pack", "ready", "assemble"} <= names, names
 
             wall = tr["duration_ms"]
             phase_sum = sum(s["duration_ms"] for s in tr["spans"]
@@ -275,10 +276,32 @@ def test_e2e_jax_trace_covers_all_phases_and_tiles_wall_time():
         metrics = (await client.get("/metrics")).body.decode()
         for phase in ("authn", "match", "queue_wait", "execute",
                       "respfilter", "upstream"):
-            assert (f'authz_request_phase_seconds_count{{phase="{phase}"}}'
-                    in metrics), phase
+            assert (f'authz_request_phase_seconds_count{{phase="{phase}",'
+                    f'verb="get"}}' in metrics), phase
 
     asyncio.run(run())
+
+
+def test_phase_histogram_is_labelled_by_request_verb():
+    """authz_request_phase_seconds carries the request's kube verb, so
+    the phases of lists and of gets read apart from /metrics."""
+    proxy, _ = make_proxy()
+
+    async def run():
+        client = proxy.get_embedded_client(user="alice")
+        assert (await client.get(
+            "/api/v1/namespaces/team-a/pods/p0")).status == 200
+        assert (await client.get("/api/v1/pods")).status == 200
+        return (await client.get("/metrics")).body.decode()
+
+    metrics = asyncio.run(run())
+    for phase, verb in (("execute", "get"), ("upstream", "get"),
+                        ("upstream", "list"), ("respfilter", "list")):
+        assert (f'authz_request_phase_seconds_count{{phase="{phase}",'
+                f'verb="{verb}"}}' in metrics), (phase, verb)
+    # every series of the family names its verb
+    assert 'authz_request_phase_seconds_count{phase="execute"}' \
+        not in metrics
 
 
 def test_e2e_list_request_attributes_prefilter_kernel_time():

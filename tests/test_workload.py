@@ -325,7 +325,7 @@ class TestGateOffTripwire:
             assert wa.note_sweep("ell", "check", np.asarray([2, 3, 1])) \
                 is None
             wa.note_batch([("doc", "view", 4)], "check", iterations=3)
-            wa.note_device_time([("doc", "view", 4)], "kernel.device", 0.01)
+            wa.note_device_time([("doc", "view", 4)], "ready", 0.01)
             wa.note_oracle([("doc", "view", 1)])
             wa.note_cache("doc", "view", 2, 1)
             payload = wa.payload()
@@ -346,7 +346,7 @@ class TestWorkloadAccounting:
     def test_device_time_split_by_row_share(self):
         wa = workload.WorkloadAccounting(registry=m.Registry())
         wa.note_device_time([("doc", "view", 3), ("doc", "edit", 1)],
-                            "kernel.device", 0.04)
+                            "ready", 0.04)
         payload = wa.payload()
         rows = {(r["resource_type"], r["permission"]): r
                 for r in payload["rows"]}
@@ -358,7 +358,7 @@ class TestWorkloadAccounting:
         """Spans with no composition (warmup, rebuild flushes) must
         show up in the reconciliation denominator, not vanish."""
         wa = workload.WorkloadAccounting(registry=m.Registry())
-        wa.note_device_time(None, "kernel.device", 0.02)
+        wa.note_device_time(None, "ready", 0.02)
         payload = wa.payload()
         assert payload["total_device_s"] == pytest.approx(0.02)
         assert payload["attributed_device_s"] == 0.0
@@ -382,14 +382,24 @@ class TestWorkloadAccounting:
 
     def test_devtel_hook_feeds_same_seconds(self):
         """The /debug/workload device-time total is fed by the same
-        kernel-span seconds as authz_kernel_time_seconds (the devtel
-        hook forwards them), so the two reconcile by construction."""
-        from spicedb_kubeapi_proxy_tpu.utils import devtel
-        before = workload.WORKLOAD.payload()["total_device_s"]
-        devtel.note_kernel_span(
-            "kernel.device", {"workload": [("doc", "view", 2)]}, 0.015)
-        after = workload.WORKLOAD.payload()["total_device_s"]
-        assert after - before == pytest.approx(0.015, abs=1e-6)
+        `ready` span seconds as authz_dispatch_stage_seconds{stage=
+        "ready"} (the endpoint forwards the span's duration), so the two
+        reconcile by construction."""
+        from spicedb_kubeapi_proxy_tpu.utils import metrics as m_
+        ep = make_endpoint()
+        run(ep.check_bulk_permissions(check_reqs()))  # warm (compile)
+        hist = m_.REGISTRY.get("authz_dispatch_stage_seconds")
+
+        def ready_s():
+            return sum(s for (stage, _k), (_c, s, _n) in hist.raw().items()
+                       if stage == "ready")
+
+        before = (workload.WORKLOAD.payload()["total_device_s"], ready_s())
+        run(ep.check_bulk_permissions(check_reqs()))
+        after = (workload.WORKLOAD.payload()["total_device_s"], ready_s())
+        assert after[1] > before[1]
+        assert after[0] - before[0] == pytest.approx(after[1] - before[1],
+                                                     abs=1e-5)
 
 
 # -- Leopard-candidate detection ----------------------------------------------
