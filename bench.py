@@ -416,17 +416,17 @@ def roofline_probe(ep, workload, batch: int) -> dict:
     nt = n + a
     # fanin widths from the ACTUAL tables (K layout is env-tunable)
     k_main = int(graph.dev_main.shape[1])
-    k_aux = int(graph.dev_aux.shape[1])
     k_cav = int(graph.dev_cav.shape[1]) if kern.planes else 0
     w_total = 2 * n_words if kern.planes else n_words
     state_bytes = nt * w_total * 4
-    # the bottom-up aux refresh sweeps the aux table aux_passes times per
-    # outer iteration (Gauss-Seidel tree collapse)
-    ap = getattr(kern, "aux_passes", 1)
-    gather_bytes = 4 * w_total * (n * (k_main + 1) + ap * a * (k_aux + 1))
+    # aux slots the compiled step gathers per outer iteration: each
+    # slice once per pass of its stage (staged step), or the whole table
+    # aux_passes times (Jacobi step)
+    aux_slots = sum(kern.aux_refresh_slots().values())
+    gather_bytes = 4 * w_total * (n * (k_main + 1) + aux_slots)
     if kern.planes:
         gather_bytes += 4 * w_total * nt * (k_cav + 1)
-    table_bytes = 4 * (n * k_main + ap * a * k_aux
+    table_bytes = 4 * (n * k_main + aux_slots
                        + (nt * k_cav if kern.planes else 0))
     per_iter = gather_bytes + 2 * state_bytes + table_bytes
     total_bytes = per_iter * max(iters, 1)
@@ -464,7 +464,7 @@ def roofline_probe(ep, workload, batch: int) -> dict:
     gather_pass_s = max((time.perf_counter() - g0 - rtt) / 20, 1e-9)
     ns_per_row = gather_pass_s / nt * 1e9
     # per-sweep gather rows: K_MAIN over state + aux refreshes
-    sweep_rows = n * k_main + ap * a * k_aux
+    sweep_rows = n * k_main + aux_slots
     floor_s = sweep_rows * (ns_per_row / 1e9) * max(iters, 1)
     return {
         "state_rows": nt,

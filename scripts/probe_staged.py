@@ -14,7 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from spicedb_kubeapi_proxy_tpu.models import workloads as wl
-from spicedb_kubeapi_proxy_tpu.ops.ell import compute_stages, make_ell_evaluate
+from spicedb_kubeapi_proxy_tpu.ops.ell import make_ell_evaluate
 from spicedb_kubeapi_proxy_tpu.ops.jax_endpoint import JaxEndpoint
 from spicedb_kubeapi_proxy_tpu.spicedb import schema as sch
 from spicedb_kubeapi_proxy_tpu.spicedb.types import SubjectRef, parse_relationship
@@ -35,7 +35,7 @@ def main():
     rng = prog.slot_range(w.resource_type, w.permission)
     n_words = max(1, len(q_arr) // 32)
     kern = graph.kernel
-    stages = compute_stages(prog)
+    stages = kern.stages
     print(f"stages: {len(stages)} ranges {stages[:8]}", flush=True)
 
     q = jnp.asarray(q_arr)
@@ -43,7 +43,8 @@ def main():
     for name, st in (("jacobi", None), ("staged", stages)):
         evaluate = make_ell_evaluate(prog, kern.n_aux_rows, n_words,
                                      kern.num_iters,
-                                     aux_passes=kern.aux_passes, stages=st)
+                                     aux_passes=kern.aux_passes, stages=st,
+                                     aux_slices=kern.aux_slices)
 
         def run_lookup(q_idx, idx_main, idx_aux):
             x = evaluate(q_idx, idx_main, idx_aux)
@@ -66,7 +67,8 @@ def main():
             make_ell_step,
         )
         step = make_ell_step(prog, kern.n_aux_rows,
-                             aux_passes=kern.aux_passes, stages=st)
+                             aux_passes=kern.aux_passes, stages=st,
+                             aux_slices=kern.aux_slices)
 
         def count_iters(q_idx, idx_main, idx_aux):
             x0 = init_packed_state(prog, kern.n_aux_rows, q_idx, n_words)

@@ -15,12 +15,20 @@ The high-performance variant of ops/spmv.py.  Two ideas:
    are split into an OR-reduction tree of **aux nodes** appended after the
    real state rows: each aux node ORs up to K2 children, levels stacked
    until ≤K1 roots remain.  Aux nodes are stateless OR gates recomputed
-   every iteration; they add tree-depth extra iterations (each ~100x
-   cheaper than a segment-path iteration) but keep every row's fanin
-   static.  Monotonicity of the fixpoint makes this exactly equivalent to
-   the flat edge list (reference semantics: SpiceDB's recursive graph
-   walk, pkg/authz/check.go:48, bounded like dispatch depth
+   from the state rows; they keep every row's fanin static.  Monotonicity
+   of the fixpoint makes this exactly equivalent to the flat edge list
+   (reference semantics: SpiceDB's recursive graph walk,
+   pkg/authz/check.go:48, bounded like dispatch depth
    pkg/spicedb/spicedb.go:34).
+
+   The aux table is NOT small on real graphs: direct per-object grants
+   (a pod with 3..18 viewers) each grow a tree, so on the multitenant
+   150k-pod graph the aux table holds 5.5M slots against the main
+   table's 2.3M, only 15% of them live.  `build_tables` therefore
+   orders the aux rows into slices by (reading stage, tree level, live
+   width rounded up to a power of two), and the staged step refreshes
+   each slice once per pass of the one stage that reads it, at its live
+   width — not every row at full width before every stage.
 
 2. **Bit-packed batch.**  The boolean state for a B-query batch is packed
    into uint32 words: x is [NT, W] with W = B/32.  HBM traffic drops 32x
@@ -29,8 +37,9 @@ The high-performance variant of ops/spmv.py.  Two ideas:
 
 Layout: rows [0, state_size) are the GraphProgram's state (slot ranges
 unchanged, so permission-op slices and lookup slices work as before);
-rows [state_size, NT) are aux tree nodes.  The program's dead index keeps
-its position; padding slots in both tables point at it.
+rows [state_size, NT) are aux tree nodes: the built slices, then the
+spare pool for delta growth.  The program's dead index keeps its
+position; padding slots in both tables point at it.
 """
 
 from __future__ import annotations
@@ -92,6 +101,63 @@ class EllTables:
     # insert hitting a full main row moves the row's children into one of
     # these and gains an OR-tree level instead of forcing a rebuild
     spare_rows: tuple = ()               # aux-table row numbers
+    # (stage, level, lo, hi, width): built aux rows [lo, hi) are the
+    # level-`level` nodes of trees rooted in compute_stages' stage
+    # `stage` (len(stages) for roots in no stage), each with at most
+    # `width` live children, all in columns [0, width)
+    aux_slices: tuple = ()
+
+
+def _pow2_ceil(a: np.ndarray) -> np.ndarray:
+    """Smallest power of two >= each element (>= 1)."""
+    out = np.ones_like(a)
+    while (out < a).any():
+        out = np.where(out < a, out * 2, out)
+    return out
+
+
+def aux_row_widths(n_rows: int, slices: tuple) -> np.ndarray:
+    """Per aux row, the columns the staged step refreshes: the slice
+    width for built rows, K_AUX for spare (and any other) rows.  A delta
+    insert may only fill a dead slot below its row's width."""
+    w = np.full(n_rows, K_AUX, np.int32)
+    for _, _, lo, hi, width in slices:
+        w[lo:hi] = width
+    return w
+
+
+def _order_aux(prog: GraphProgram, idx_main: np.ndarray,
+               idx_aux: np.ndarray, root: np.ndarray, level: np.ndarray,
+               live: np.ndarray) -> tuple:
+    """Renumber built aux rows so each (stage of the root row, level,
+    power-of-two live width) group is one contiguous range; rewrite the
+    aux references in both tables to match.  Returns (idx_main, idx_aux,
+    slices)."""
+    n = prog.state_size
+    stages = compute_stages(prog)
+    stage = np.full(len(root), len(stages), np.int64)
+    for i, (ranges, _, _) in enumerate(stages):
+        for lo, hi in ranges:
+            stage[(root >= lo) & (root < hi)] = i
+    width = _pow2_ceil(live)
+    order = np.lexsort((width, level, stage))
+    new_of = np.empty(len(order), np.int64)
+    new_of[order] = np.arange(len(order))
+
+    def remap(t: np.ndarray) -> np.ndarray:
+        is_aux = t >= n
+        out = t.copy()
+        out[is_aux] = n + new_of[t[is_aux] - n]
+        return out
+
+    keys = np.stack([stage[order], level[order], width[order]], axis=1)
+    cut = np.nonzero((np.diff(keys, axis=0) != 0).any(axis=1))[0] + 1
+    bounds = np.concatenate([[0], cut, [len(order)]])
+    slices = tuple(
+        (int(keys[lo, 0]), int(keys[lo, 1]), int(lo), int(hi),
+         int(keys[lo, 2]))
+        for lo, hi in zip(bounds[:-1], bounds[1:]))
+    return remap(idx_main), remap(idx_aux[order]), slices
 
 
 def build_tables(prog: GraphProgram,
@@ -101,12 +167,16 @@ def build_tables(prog: GraphProgram,
 
     Vectorized: one stable sort by destination, then per-slot scatter for
     the (overwhelmingly common) small rows; only hub destinations fall to
-    a Python loop."""
+    a Python loop.  The built aux rows are then ordered into slices
+    (`_order_aux`), and the spare pool follows them."""
     km = k_main if k_main is not None else K_MAIN
     n = prog.state_size
     dead = prog.dead_index
     idx_main = np.full((n, km), dead, np.int32)
     aux_rows: list[np.ndarray] = []
+    aux_root: list[int] = []     # main row each built node's tree feeds
+    aux_level: list[int] = []    # 1 = children are state rows
+    aux_live: list[int] = []     # live children
     tree_depth = 0
     e = len(prog.edge_src)
     if e:
@@ -123,26 +193,34 @@ def build_tables(prog: GraphProgram,
         small_edges = np.repeat(small, counts)
         idx_main[sdst[small_edges], rank[small_edges]] = ssrc[small_edges]
 
-        def new_aux(children: np.ndarray) -> int:
+        def new_aux(children: np.ndarray, level: int, root: int) -> int:
             row = np.full(K_AUX, dead, np.int32)
             row[: len(children)] = children
             aux_rows.append(row)
+            aux_root.append(root)
+            aux_level.append(level)
+            aux_live.append(len(children))
             return n + len(aux_rows) - 1
 
         for g in np.nonzero(~small)[0]:
             lo = int(starts[g])
+            root = int(gdst[g])
             children = ssrc[lo: lo + int(counts[g])]
             depth = 0
             while len(children) > km:
                 children = np.asarray(
-                    [new_aux(children[i: i + K_AUX])
+                    [new_aux(children[i: i + K_AUX], depth + 1, root)
                      for i in range(0, len(children), K_AUX)], np.int32)
                 depth += 1
-            idx_main[int(gdst[g]), : len(children)] = children
+            idx_main[root, : len(children)] = children
             tree_depth = max(tree_depth, depth)
 
+    slices: tuple = ()
     if aux_rows:
-        idx_aux = np.stack(aux_rows).astype(np.int32)
+        idx_main, idx_aux, slices = _order_aux(
+            prog, idx_main, np.stack(aux_rows).astype(np.int32),
+            np.asarray(aux_root, np.int64), np.asarray(aux_level, np.int64),
+            np.asarray(aux_live, np.int64))
     else:
         idx_aux = np.full((0, K_AUX), dead, np.int32)
     # spare pool sized to the graph; hub-free graphs keep an empty aux
@@ -162,8 +240,10 @@ def build_tables(prog: GraphProgram,
         spares = tuple(range(spare0, spare0 + n_spare))
     else:
         spares = ()
-    return EllTables(idx_main=idx_main, idx_aux=idx_aux,
-                     tree_depth=tree_depth, spare_rows=spares)
+    return EllTables(idx_main=idx_main.astype(np.int32),
+                     idx_aux=idx_aux.astype(np.int32),
+                     tree_depth=tree_depth, spare_rows=spares,
+                     aux_slices=slices)
 
 
 @dataclass
@@ -375,48 +455,38 @@ def compute_stages(prog: GraphProgram) -> tuple:
     return tuple(stages)
 
 
-def annotate_stage_refresh(stages: tuple, host_main: np.ndarray,
-                           state_size: int) -> tuple:
-    """Set each stage's aux-refresh flag to whether its gather table rows
-    actually reference aux nodes (values >= state_size): stages that
-    never read the aux table skip the per-stage OR-tree refresh.  The
-    flags are a convergence-speed hint computed at build time — deltas
-    that later grow a tree into a flag-less stage only cost extra
-    sweeps (the while_loop still exits at the true fixpoint)."""
-    out = []
-    for ranges, repeat, _ in stages:
-        refs = any(bool((host_main[lo:hi] >= state_size).any())
-                   for lo, hi in ranges)
-        out.append((ranges, repeat, refs))
-    return tuple(out)
-
-
 def make_ell_step(prog: GraphProgram, n_aux_rows: int,
                   half: Optional[int] = None, aux_passes: int = 1,
-                  stages: Optional[tuple] = None):
+                  stages: Optional[tuple] = None, aux_slices: tuple = (),
+                  spare: Optional[tuple] = None):
     """Per-iteration transition over packed state x: [NT, W] uint32 —
     or [NT, 2*half] when the tri-state (definite/maybe bitplane) path is
     active (`half` = words per plane; an idx_cav table feeds the MAYBE
     half with the undecidable caveated edges).
 
-    `aux_passes` (= the OR-tree height) refreshes the aux nodes
-    bottom-up BEFORE the main gather reads them (Gauss-Seidel within the
-    iteration), so a hub edge propagates leaf -> tree -> destination in
-    one outer iteration instead of one per tree level.  Monotone OR
-    fixpoint semantics are unchanged — only the trip count drops.
+    Without `stages` (the Jacobi step: the plane path, and the A/B
+    switch) `aux_passes` (= the OR-tree height + 1) refreshes every aux
+    node bottom-up at full width BEFORE the main gather reads them, so a
+    hub edge propagates leaf -> tree -> destination in one outer
+    iteration instead of one per tree level.
 
     `stages` (definite path only) extends the same idea across TYPES:
     state-row ranges are updated in type-topological order within one
     sweep, each range's gather reading the ranges already updated this
     sweep, so a full user->group->...->pod chain propagates in ONE sweep
     instead of one per type hop (measured on multitenant-1m: trips 6->2,
-    scripts/probe_staged.py).  MAIN-table gather traffic per sweep is
-    unchanged; the aux OR-tree refresh runs once per aux-reading stage
-    pass instead of once per sweep (the aux table is orders of magnitude
-    smaller than the main table, but aux-hub-heavy schemas pay S-fold
-    refresh cost — annotate_stage_refresh bounds S to the stages that
-    actually read aux roots).  Per-row gather cost is lowering-bound and
-    locality-independent (same probe), so fewer sweeps is the win."""
+    scripts/probe_staged.py).  Before each pass of a stage, the aux
+    slices of `aux_slices` (build_tables) rooted in that stage are
+    refreshed once each, in level order, at their live width; the aux
+    table is as large as the main table on real graphs, so no row is
+    refreshed where no stage reads it.  `spare` = (lo, hi, passes) adds
+    the in-use prefix of the spare pool (delta growth) at full width,
+    `passes` times, before each pass of a stage flagged as reading aux.
+    Every aux row a stage reads is thus recomputed earlier in the same
+    sweep, so the sweep is a function of the state rows alone and the
+    caller's convergence test compares rows [0, state_size) only
+    (make_ell_evaluate).  Monotone OR fixpoint semantics are unchanged
+    — only the trip count and the gathered indices drop."""
     n = prog.state_size
     dead = prog.dead_index
     perm_ops = tuple(prog.perm_ops)
@@ -427,6 +497,12 @@ def make_ell_step(prog: GraphProgram, n_aux_rows: int,
         m[np.asarray(term.mask_indices, np.int64)] = np.uint32(0xFFFFFFFF)
         wc_masks.append(jnp.asarray(m))
 
+    def gather_or(cur, tbl, width):
+        y = cur[tbl[:, 0]]
+        for k in range(1, width):
+            y = y | cur[tbl[:, k]]
+        return y
+
     if stages:
         # perm ops and wildcard masks grouped by the stage whose ranges
         # contain them (slot layout keeps a type's slots contiguous, so
@@ -434,29 +510,37 @@ def make_ell_step(prog: GraphProgram, n_aux_rows: int,
         def _in_stage(ranges, off):
             return any(lo <= off < hi for lo, hi in ranges)
 
-        stage_ops = {s: [op for op in perm_ops
-                         if _in_stage(s[0], op.offset)] for s in stages}
-        stage_wc = {s: [i for i, term in enumerate(wc_terms)
-                        if any(_in_stage(s[0], m)
-                               for m in term.mask_indices)]
-                    for s in stages}
+        stage_ops = [[op for op in perm_ops if _in_stage(s[0], op.offset)]
+                     for s in stages]
+        stage_wc = [[i for i, term in enumerate(wc_terms)
+                     if any(_in_stage(s[0], m) for m in term.mask_indices)]
+                    for s in stages]
+        # level order within a stage: a level-L node reads level L-1
+        stage_slices = [sorted((lvl, lo, hi, w)
+                               for st, lvl, lo, hi, w in aux_slices
+                               if st == i)
+                        for i in range(len(stages))]
 
         def staged_step(x, x0, idx_main, idx_aux, idx_cav=None):
             assert idx_cav is None and half is None, \
                 "staged step is definite-plane only"
             cur = x
 
-            def refresh_aux(cur):
-                # hub OR-trees recomputed bottom-up from the CURRENT
-                # values (pure functions of state, safe to recompute any
-                # time); published into the carry so the next gather
-                # reads fresh roots
-                for _ in range(max(1, aux_passes)):
-                    y_aux = cur[idx_aux[:, 0]]
-                    for k in range(1, idx_aux.shape[1]):
-                        y_aux = y_aux | cur[idx_aux[:, k]]
+            def refresh(cur, i, wants_aux):
+                # this stage's OR-tree nodes recomputed from the CURRENT
+                # values (pure functions of state) and published into the
+                # carry, so the stage's gather reads fresh roots
+                for _, lo, hi, w in stage_slices[i]:
                     cur = jax.lax.dynamic_update_slice_in_dim(
-                        cur, y_aux, n, axis=0)
+                        cur, gather_or(cur, idx_aux[lo:hi], w), n + lo,
+                        axis=0)
+                if spare and wants_aux:
+                    lo, hi, passes = spare
+                    for _ in range(passes):
+                        cur = jax.lax.dynamic_update_slice_in_dim(
+                            cur, gather_or(cur, idx_aux[lo:hi],
+                                           idx_aux.shape[1]),
+                            n + lo, axis=0)
                 return cur
 
             # wildcard liveness: self slots are static seeds (set at
@@ -466,37 +550,25 @@ def make_ell_step(prog: GraphProgram, n_aux_rows: int,
                     x, t.self_offset, t.self_length, axis=0),
                 np.uint32(0), jax.lax.bitwise_or, (0,))[None, :]
                 for t in wc_terms]
-            for s in stages:
-                ranges, repeat, wants_aux = s
+            for i, (ranges, repeat, wants_aux) in enumerate(stages):
                 for _ in range(repeat):
-                    if n_aux_rows and wants_aux:
-                        # refresh before every pass of a stage whose
-                        # table reads aux roots: hub trees whose
-                        # children updated earlier this sweep feed this
-                        # stage's gather immediately
-                        cur = refresh_aux(cur)
+                    if n_aux_rows:
+                        cur = refresh(cur, i, wants_aux)
                     for lo, hi in ranges:
-                        tbl = idx_main[lo:hi]
-                        y = cur[tbl[:, 0]]
-                        for k in range(1, tbl.shape[1]):
-                            y = y | cur[tbl[:, k]]
+                        y = gather_or(cur, idx_main[lo:hi],
+                                      idx_main.shape[1])
                         y = y | jax.lax.dynamic_slice_in_dim(
                             x0, lo, hi - lo, axis=0)
-                        for i in stage_wc[s]:
-                            y = y | (wc_masks[i][lo:hi] & lives[i])
+                        for j in stage_wc[i]:
+                            y = y | (wc_masks[j][lo:hi] & lives[j])
                         cur = jax.lax.dynamic_update_slice_in_dim(
                             cur, y, lo, axis=0)
-                    for op in stage_ops[s]:
+                    for op in stage_ops[i]:
                         vec = _apply_perm_expr_packed(op.expr, cur, half)
                         seed = jax.lax.dynamic_slice_in_dim(
                             x0, op.offset, op.length, axis=0)
                         cur = jax.lax.dynamic_update_slice_in_dim(
                             cur, vec | seed, op.offset, axis=0)
-            if n_aux_rows:
-                # leave aux rows consistent with this sweep's final
-                # state so the convergence compare (any(x1 != x)) sees a
-                # fixpoint as unchanged aux too
-                cur = refresh_aux(cur)
             return cur.at[dead].set(np.uint32(0))
 
         return staged_step
@@ -512,26 +584,17 @@ def make_ell_step(prog: GraphProgram, n_aux_rows: int,
             # state rows), then the main gather reads current roots
             xm = x
             for _ in range(max(1, aux_passes)):
-                y_aux = xm[idx_aux[:, 0]]
-                for k in range(1, idx_aux.shape[1]):
-                    y_aux = y_aux | xm[idx_aux[:, k]]
+                y_aux = gather_or(xm, idx_aux, idx_aux.shape[1])
                 xm = jnp.concatenate([x[:n], y_aux], axis=0)
-            y_main = xm[idx_main[:, 0]]
-            for k in range(1, idx_main.shape[1]):
-                y_main = y_main | xm[idx_main[:, k]]
+            y_main = gather_or(xm, idx_main, idx_main.shape[1])
             y = jnp.concatenate([y_main, y_aux], axis=0)
         else:
-            y_main = x[idx_main[:, 0]]
-            for k in range(1, idx_main.shape[1]):
-                y_main = y_main | x[idx_main[:, k]]
-            y = y_main
+            y = gather_or(x, idx_main, idx_main.shape[1])
         if idx_cav is not None:
             # caveat edges reach the MAYBE plane only: gather their
             # closure and OR it into the maybe half (definite half is
             # untouched — an undecided caveat can never DEFINITELY grant)
-            extra = x[idx_cav[:, 0]]
-            for k in range(1, idx_cav.shape[1]):
-                extra = extra | x[idx_cav[:, k]]
+            extra = gather_or(x, idx_cav, idx_cav.shape[1])
             y = jnp.concatenate([y[:, :half], y[:, half:] | extra[:, half:]],
                                 axis=1)
         for term, mask in zip(wc_terms, wc_masks):
@@ -584,11 +647,16 @@ def init_packed_state(prog: GraphProgram, n_aux_rows: int, q_idx,
     return x0.at[prog.dead_index].set(np.uint32(0))
 
 
+def _head(x: jnp.ndarray, rows: Optional[int]) -> jnp.ndarray:
+    return x if rows is None else x[:rows]
+
+
 def make_ell_evaluate(prog: GraphProgram, n_aux_rows: int, n_words: int,
                       num_iters: int, use_while: bool = True,
                       planes: bool = False, aux_passes: int = 1,
                       stages: Optional[tuple] = None, arena: bool = False,
-                      introspect: bool = False):
+                      introspect: bool = False, aux_slices: tuple = (),
+                      spare: Optional[tuple] = None):
     """fn(q_idx, idx_main, idx_aux[, idx_cav]) -> packed x_final
     [NT, W] uint32 ([NT, 2W] on the tri-state plane path).
 
@@ -605,10 +673,14 @@ def make_ell_evaluate(prog: GraphProgram, n_aux_rows: int, n_words: int,
     changed, via popcount of x1 ^ x).  The trace rides the carry and is
     read back with the result D2H, so it adds no device sync; off, the
     carry is byte-identical to the pre-introspection build."""
+    stages = None if planes else stages
     step = make_ell_step(prog, n_aux_rows,
                          half=n_words if planes else None,
-                         aux_passes=aux_passes,
-                         stages=None if planes else stages)
+                         aux_passes=aux_passes, stages=stages,
+                         aux_slices=aux_slices, spare=spare)
+    # rows the convergence test compares: a staged sweep is a function
+    # of the state rows alone (make_ell_step); the Jacobi step, all rows
+    conv = prog.state_size if stages else None
 
     def fixpoint(x0, idx_main, idx_aux, idx_cav):
         if use_while:
@@ -620,8 +692,8 @@ def make_ell_evaluate(prog: GraphProgram, n_aux_rows: int, n_words: int,
                 def body(state):
                     x, _, i, trace = state
                     x1 = step(x, x0, idx_main, idx_aux, idx_cav)
-                    delta = jnp.sum(
-                        jax.lax.population_count(x1 ^ x)).astype(jnp.int32)
+                    delta = jnp.sum(jax.lax.population_count(
+                        _head(x1, conv) ^ _head(x, conv))).astype(jnp.int32)
                     return (x1, delta > jnp.int32(0), i + 1,
                             trace.at[i].set(delta))
 
@@ -637,7 +709,8 @@ def make_ell_evaluate(prog: GraphProgram, n_aux_rows: int, n_words: int,
             def body(state):
                 x, _, i = state
                 x1 = step(x, x0, idx_main, idx_aux, idx_cav)
-                return (x1, jnp.any(x1 != x), i + 1)
+                return (x1, jnp.any(_head(x1, conv) != _head(x, conv)),
+                        i + 1)
 
             x_final, _, _ = jax.lax.while_loop(
                 cond, body, (x0, jnp.bool_(True), jnp.int32(0)))
@@ -646,8 +719,8 @@ def make_ell_evaluate(prog: GraphProgram, n_aux_rows: int, n_words: int,
         if introspect:
             def body(x, _):
                 x1 = step(x, x0, idx_main, idx_aux, idx_cav)
-                delta = jnp.sum(
-                    jax.lax.population_count(x1 ^ x)).astype(jnp.int32)
+                delta = jnp.sum(jax.lax.population_count(
+                    _head(x1, conv) ^ _head(x, conv))).astype(jnp.int32)
                 return x1, delta
 
             x_final, deltas = jax.lax.scan(body, x0, None, length=num_iters)
@@ -689,17 +762,17 @@ class EllKernelCache:
     def __init__(self, prog: GraphProgram, n_aux_rows: int, tree_depth: int,
                  num_iters: Optional[int] = None, planes: bool = False,
                  shared_tree_depth: Optional[int] = None,
-                 host_main: Optional[np.ndarray] = None):
+                 aux_slices: tuple = (), spare_rows: tuple = ()):
         self.prog = prog
         self.n_aux_rows = n_aux_rows
         self.planes = planes
-        # in-step bottom-up aux refresh (Gauss-Seidel) collapses OR-tree
-        # levels into their outer iteration.  Passes follow the SHARED
-        # table's tree height only — cav trees propagate through idx_cav
-        # one level per outer iteration regardless, so their depth must
-        # not inflate the sweep count (callers fold it into tree_depth
-        # for the cap).  +1 spare pass: incremental growth can add a
-        # level beyond the built height.
+        # the Jacobi step's in-step bottom-up aux refresh (Gauss-Seidel)
+        # collapses OR-tree levels into their outer iteration.  Passes
+        # follow the SHARED table's tree height only — cav trees
+        # propagate through idx_cav one level per outer iteration
+        # regardless, so their depth must not inflate the sweep count
+        # (callers fold it into tree_depth for the cap).  +1 spare pass:
+        # incremental growth can add a level beyond the built height.
         std = shared_tree_depth if shared_tree_depth is not None else tree_depth
         self.aux_passes = std + 1
         # generous cap — while_loop exits at the true fixpoint anyway
@@ -712,9 +785,23 @@ class EllKernelCache:
                        if not planes
                        and os.environ.get("SPICEDB_TPU_STAGED", "1") != "0"
                        else None)
-        if self.stages and host_main is not None:
-            self.stages = annotate_stage_refresh(self.stages, host_main,
-                                                 prog.state_size)
+        # build_tables' aux slices; a stage reads aux (wants_aux) exactly
+        # when some slice is rooted in it, until delta growth flips it
+        # (note_main_aux_ref)
+        self.aux_slices = tuple(aux_slices)
+        if self.stages:
+            rooted = {sl[0] for sl in self.aux_slices}
+            self.stages = tuple((ranges, repeat, i in rooted)
+                                for i, (ranges, repeat, _)
+                                in enumerate(self.stages))
+        # spare pool rows are taken in order (_EllGraph._grow); the staged
+        # step refreshes the prefix [spare_base, spare_base + spare_prefix)
+        # `spare_passes` times, both trace-time constants grown by
+        # note_spare_row
+        self.spare_base = spare_rows[0] if len(spare_rows) else 0
+        self.spare_pool = len(spare_rows)
+        self.spare_prefix = 0
+        self.spare_passes = 0
         self._jits: dict[int, tuple] = {}
         # donated per-bucket state arenas (device-resident pipeline):
         # the pipelined entry points return their final sweep state, and
@@ -752,8 +839,77 @@ class EllKernelCache:
                 return True
         return False
 
+    def note_spare_row(self, row: int, depth: int) -> bool:
+        """_EllGraph._grow took spare aux row `row` (aux-table number),
+        `depth` spare levels above the built tree.  If the row lies past
+        the compiled spare prefix, or its chain is deeper than the
+        compiled passes, grow them (the prefix to a power of two, so a run
+        of inserts re-jits O(log n) times) and drop the compiled entry
+        points; returns True when that happened.  The Jacobi step
+        refreshes every aux row already."""
+        if not self.stages:
+            return False
+        need = row - self.spare_base + 1
+        if need <= self.spare_prefix and depth <= self.spare_passes:
+            return False
+        prefix = max(1, self.spare_prefix)
+        while prefix < need:
+            prefix *= 2
+        self.spare_prefix = min(prefix, self.spare_pool)
+        self.spare_passes = max(self.spare_passes, depth)
+        self._jits = {}
+        workload.note_spare_rejit()
+        return True
+
+    def _spare(self) -> Optional[tuple]:
+        if not self.spare_prefix:
+            return None
+        return (self.spare_base, self.spare_base + self.spare_prefix,
+                self.spare_passes)
+
+    def aux_refresh_slots(self) -> dict:
+        """Aux-table indices one sweep of the compiled step gathers, by
+        kind: `slices` (built OR-tree slices, per stage pass), `spare`
+        (the spare-pool prefix) and `table` (the Jacobi step: every row
+        at full width, `aux_passes` times)."""
+        out = {"slices": 0, "spare": 0, "table": 0}
+        if not self.n_aux_rows:
+            return out
+        if not self.stages:
+            out["table"] = self.aux_passes * self.n_aux_rows * K_AUX
+            return out
+        spare = self._spare()
+        for i, (_, repeat, wants_aux) in enumerate(self.stages):
+            out["slices"] += repeat * sum(
+                (hi - lo) * w for st, _, lo, hi, w in self.aux_slices
+                if st == i)
+            if spare and wants_aux:
+                out["spare"] += repeat * spare[2] * (spare[1] - spare[0]) \
+                    * K_AUX
+        return out
+
+    def _evaluate(self, n_words: int, **kw):
+        """make_ell_evaluate for this cache's program and tables at the
+        current stage flags and spare prefix; records the compiled
+        step's aux refresh in authz_aux_refresh_slots.
+
+        Callers take `self._jits` BEFORE this reads the config and store
+        into that dict: note_main_aux_ref / note_spare_row change the
+        config first and then replace the dict, so an entry built from
+        the old config by a concurrent caller lands in the dropped dict,
+        never in the new one (a step that skips a spare row a table
+        reads is wrong, not only slower)."""
+        workload.note_aux_refresh(self.aux_refresh_slots())
+        return make_ell_evaluate(self.prog, self.n_aux_rows, n_words,
+                                 self.num_iters, planes=self.planes,
+                                 aux_passes=self.aux_passes,
+                                 stages=self.stages,
+                                 aux_slices=self.aux_slices,
+                                 spare=self._spare(), **kw)
+
     def _fns(self, n_words: int) -> tuple:
-        fns = self._jits.get(n_words)
+        jits = self._jits  # before reading the step's config: see _evaluate
+        fns = jits.get(n_words)
         if fns is not None:
             devtel.KERNELS.note_jit_hit(n_words * 32)
             return fns
@@ -763,10 +919,7 @@ class EllKernelCache:
         # trace in the carry, scalar return shapes) — the killswitch is
         # byte-identical, not merely quiet
         intro = workload.enabled()
-        evaluate = make_ell_evaluate(self.prog, self.n_aux_rows, n_words,
-                                     self.num_iters, planes=self.planes,
-                                     aux_passes=self.aux_passes,
-                                     stages=self.stages, introspect=intro)
+        evaluate = self._evaluate(n_words, introspect=intro)
         if self.planes:
             def run_checks(q_idx, gather_idx, gather_word, gather_bit,
                            idx_main, idx_aux, idx_cav):
@@ -822,7 +975,7 @@ class EllKernelCache:
                    jax.jit(run_lookup, static_argnums=(0, 1)),
                    bucket=n_words * 32, static_args=2, shape_args=True),
                intro)
-        self._jits[n_words] = fns
+        jits[n_words] = fns
         return fns
 
     # -- pipelined (device-resident) entry points ----------------------------
@@ -835,7 +988,8 @@ class EllKernelCache:
     # the D2H readback with the next batch's dispatch.
 
     def _pipe_fns(self, n_words: int) -> tuple:
-        fns = self._jits.get(("pipe", n_words))
+        jits = self._jits
+        fns = jits.get(("pipe", n_words))
         if fns is not None:
             devtel.KERNELS.note_jit_hit(n_words * 32)
             return fns
@@ -844,11 +998,7 @@ class EllKernelCache:
         # the pipelined entries return (out, state, tel) and the sweep
         # trace rides the same async D2H the result does
         intro = workload.enabled()
-        evaluate = make_ell_evaluate(self.prog, self.n_aux_rows, n_words,
-                                     self.num_iters, planes=self.planes,
-                                     aux_passes=self.aux_passes,
-                                     stages=self.stages, arena=True,
-                                     introspect=intro)
+        evaluate = self._evaluate(n_words, arena=True, introspect=intro)
         if self.planes:
             def run_checks(q_idx, gather_idx, gather_col, state,
                            idx_main, idx_aux, idx_cav):
@@ -905,7 +1055,7 @@ class EllKernelCache:
                            donate_argnums=(3,)),
                    bucket=n_words * 32, static_args=2, shape_args=True),
                intro)
-        self._jits[("pipe", n_words)] = fns
+        jits[("pipe", n_words)] = fns
         return fns
 
     def arena_key(self, lanes: int) -> int:
@@ -1001,37 +1151,21 @@ class EllKernelCache:
         bench's roofline probe (bytes-per-iteration x iterations =
         modeled HBM traffic).  Jitted separately; same step function."""
         key = ("iters", n_words)
-        fn = self._jits.get(key)
+        jits = self._jits
+        fn = jits.get(key)
         if fn is not None:
             devtel.KERNELS.note_jit_hit(n_words * 32)
         else:
             devtel.KERNELS.note_compile(n_words * 32)
-            step = make_ell_step(self.prog, self.n_aux_rows,
-                                 half=n_words if self.planes else None,
-                                 aux_passes=self.aux_passes,
-                                 stages=self.stages)
-            num_iters = self.num_iters
-            prog, n_aux, planes = self.prog, self.n_aux_rows, self.planes
+            evaluate = self._evaluate(n_words, introspect=True)
 
             def run(q_idx, idx_main, idx_aux, idx_cav=None):
-                x0 = init_packed_state(prog, n_aux, q_idx, n_words, planes)
-
-                def cond(state):
-                    x, prev_changed, i = state
-                    return jnp.logical_and(prev_changed, i < num_iters)
-
-                def body(state):
-                    x, _, i = state
-                    x1 = step(x, x0, idx_main, idx_aux, idx_cav)
-                    return (x1, jnp.any(x1 != x), i + 1)
-
-                _, _, i = jax.lax.while_loop(
-                    cond, body, (x0, jnp.bool_(True), jnp.int32(0)))
-                return i
+                _, tel = evaluate(q_idx, idx_main, idx_aux, idx_cav)
+                return tel[0]
 
             fn = timeline.time_first_call(jax.jit(run),
                                           bucket=n_words * 32)
-            self._jits[key] = fn
+            jits[key] = fn
         if self.planes:
             return int(fn(jnp.asarray(q_idx), idx_main, idx_aux, idx_cav))
         return int(fn(jnp.asarray(q_idx), idx_main, idx_aux))

@@ -82,7 +82,12 @@ from ..spicedb.types import (
     WatchUpdate,
     WILDCARD,
 )
-from .ell import EllKernelCache, batch_words, build_tables
+from .ell import (
+    EllKernelCache,
+    aux_row_widths,
+    batch_words,
+    build_tables,
+)
 from .graph_compile import (GraphProgram, caveat_affected_pairs,
                             compile_graph, compile_graph_columnar)
 from .spmv import KernelCache, bucket, pad_edges, pad_scatter
@@ -272,9 +277,11 @@ def _sweep_bytes(graph, lanes: int) -> int:
     if cached is None:
         if hasattr(graph, "dev_main"):
             n, km = graph.dev_main.shape
-            a_rows, ka = graph.dev_aux.shape
-            ap = getattr(graph.kernel, "aux_passes", 1)
-            rows = n * (km + 1) + ap * a_rows * (ka + 1)
+            a_rows = graph.dev_aux.shape[0]
+            # aux slots the compiled step gathers (its row writes are
+            # left out: the model stays a lower bound)
+            rows = n * (km + 1) + sum(
+                graph.kernel.aux_refresh_slots().values())
             if getattr(graph, "dev_cav", None) is not None:
                 rows += (n + a_rows) * (graph.dev_cav.shape[1] + 1)
             cached = (rows, True)   # packed: 4 bytes per 32 lanes
@@ -815,7 +822,7 @@ class _EllGraph(_PrewarmMixin):
             self.dev_cav = None
         self.host_main = t.idx_main
         self.host_aux = t.idx_aux
-        self._spare_aux = list(t.spare_rows)
+        self._init_aux_growth(t)
         self.dev_main = jnp.asarray(t.idx_main)
         self.dev_aux = jnp.asarray(t.idx_aux)
         self.kernel = EllKernelCache(prog, n_aux_rows=t.idx_aux.shape[0],
@@ -823,17 +830,28 @@ class _EllGraph(_PrewarmMixin):
                                      num_iters=num_iters,
                                      planes=self.has_cav,
                                      shared_tree_depth=t.tree_depth,
-                                     host_main=t.idx_main)
+                                     aux_slices=t.aux_slices,
+                                     spare_rows=t.spare_rows)
         self._dirty_main: set = set()
         self._dirty_aux: set = set()
         self._dirty_cav: set = set()
-        self._grow_extra: dict = {}  # root row -> levels grown past build
         # growths that flipped a build-time aux-free stage annotation
         # (surfaced as the endpoint's stage_aux_flips stat)
         self.stage_aux_flips = 0
         # first cav-aux row index: values >= this in the cav table are
         # OR-tree nodes whose children live in the cav table itself
         self._cav_aux_base = prog.state_size + a_shared
+
+    def _init_aux_growth(self, t) -> None:
+        """Delta-growth state over host_aux (built from tables `t`)."""
+        # spare rows are taken in order, so the staged step refreshes a
+        # prefix of the pool (EllKernelCache.note_spare_row)
+        self._spare_aux = collections.deque(t.spare_rows)
+        # columns a delta insert may fill: a built row's slice width
+        self._aux_width = aux_row_widths(self.host_aux.shape[0],
+                                         t.aux_slices)
+        self._spare_depth: dict = {}  # spare row -> spare levels it tops
+        self._grow_extra: dict = {}  # root row -> levels grown past build
 
     def index_tuples(self, tuples: list) -> None:
         pass  # positionless — nothing to index
@@ -847,8 +865,9 @@ class _EllGraph(_PrewarmMixin):
         stack = [("m", root_row)]
         while stack:
             table, row = stack.pop()
-            arr = self.host_main if table == "m" else self.host_aux
-            for col, v in enumerate(arr[row]):
+            vals = (self.host_main[row] if table == "m"
+                    else self.host_aux[row, : self._aux_width[row]])
+            for col, v in enumerate(vals):
                 v = int(v)
                 if v == want:
                     return (table, row, col)
@@ -882,13 +901,12 @@ class _EllGraph(_PrewarmMixin):
             return True
         return self._remove_pairs(pairs)
 
-    # Repeated growth on one destination nests OR-tree levels beyond the
-    # single extra level the kernel's Gauss-Seidel sweep budget
-    # (aux_passes = shared_tree_depth + 1) covers; correctness survives
-    # via the outer while_loop fixpoint, but each level past the budget
-    # costs one extra outer iteration for queries touching that hub.
-    # Cap the degradation: past this many extra levels on one root, fall
-    # back to a rebuild (which recompiles with the true tree height).
+    # Repeated growth on one destination nests OR-tree levels: the staged
+    # step re-jits with one more spare pass, the Jacobi step (passes =
+    # shared_tree_depth + 1) pays an extra outer iteration per level past
+    # its budget for queries touching that hub.  Cap it: past this many
+    # extra levels on one root, fall back to a rebuild (which recompiles
+    # with the true tree height).
     _GROW_EXTRA_MAX = 3
 
     def _grow(self, root_row: int, src: int) -> bool:
@@ -896,8 +914,8 @@ class _EllGraph(_PrewarmMixin):
         row's direct entries into a spare aux node, append `src` there,
         and point the row at the node — one extra OR-tree level for this
         destination, no rebuild.  Monotone OR gates make this exactly
-        equivalent; the first extra level rides the aux_passes budget and
-        levels past _GROW_EXTRA_MAX force a rebuild (see above)."""
+        equivalent; levels past _GROW_EXTRA_MAX force a rebuild (see
+        above)."""
         if not self._spare_aux:
             return False
         grown = self._grow_extra.get(root_row, 0)
@@ -908,8 +926,11 @@ class _EllGraph(_PrewarmMixin):
             # K_MAIN tuned >= K_AUX: the row's children + the new source
             # don't fit one aux node — fall back to the rebuild path
             return False
-        j = self._spare_aux.pop()
+        j = self._spare_aux.popleft()
         n = self.prog.state_size
+        depth = 1 + max((self._spare_depth.get(int(v) - n, 0)
+                         for v in row if v >= n), default=0)
+        self._spare_depth[j] = depth
         self.host_aux[j, : len(row)] = row
         self.host_aux[j, len(row)] = src
         self._dirty_aux.add(j)
@@ -923,6 +944,10 @@ class _EllGraph(_PrewarmMixin):
         note = getattr(self.kernel, "note_main_aux_ref", None)
         if note is not None and note(root_row):
             self.stage_aux_flips += 1
+        # the staged step must now refresh row j, `depth` passes deep
+        note = getattr(self.kernel, "note_spare_row", None)
+        if note is not None:
+            note(j, depth)
         return True
 
     def add_rel(self, rel: Relationship) -> bool:
@@ -1156,11 +1181,10 @@ class _ShardedEllGraph(_EllGraph):
         self.supports_cav_deltas = True
         self.host_cav = self.kernel.host_cav_compile
         self._cav_aux_base = prog.state_size + self.kernel.n_aux_shared
-        self._spare_aux = list(t.spare_rows)
+        self._init_aux_growth(t)
         self._dirty_main: set = set()
         self._dirty_aux: set = set()
         self._dirty_cav: set = set()
-        self._grow_extra: dict = {}  # root row -> levels grown past build
         self.stage_aux_flips = 0  # sharded kernel has no staged step
 
     def flush(self) -> bool:

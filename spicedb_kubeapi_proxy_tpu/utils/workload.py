@@ -110,6 +110,30 @@ class WorkloadAccounting:
             "iterations (near 0 = fast convergence, near 1 = deep "
             "nested propagation)",
             labels=("kernel", "verb"), buckets=_DECAY_BUCKETS)
+        # compiled-step shape, set when an ell entry point is jitted
+        self._aux_slots = registry.gauge(
+            "authz_aux_refresh_slots",
+            "Aux OR-tree indices one sweep of the most recently compiled "
+            "ell step gathers: built slices, the spare-pool prefix, or "
+            "the whole table (Jacobi step)",
+            labels=("kind",))
+        self._spare_rejits = registry.counter(
+            "authz_aux_spare_rejits_total",
+            "Compiled ell steps dropped because delta growth crossed the "
+            "refreshed spare-pool prefix")
+
+    # -- compiled aux refresh -----------------------------------------------
+
+    def note_aux_refresh(self, slots: dict) -> None:
+        if not enabled():
+            return
+        for kind, n in slots.items():
+            self._aux_slots.set(float(n), kind=kind)
+
+    def note_spare_rejit(self) -> None:
+        if not enabled():
+            return
+        self._spare_rejits.inc()
 
     # -- measured sweep telemetry -------------------------------------------
 
@@ -390,6 +414,14 @@ WORKLOAD = WorkloadAccounting()
 
 def note_sweep(kernel: str, verb: str, tel) -> Optional[SweepRecord]:
     return WORKLOAD.note_sweep(kernel, verb, tel)
+
+
+def note_aux_refresh(slots: dict) -> None:
+    WORKLOAD.note_aux_refresh(slots)
+
+
+def note_spare_rejit() -> None:
+    WORKLOAD.note_spare_rejit()
 
 
 def take_last_sweep() -> Optional[SweepRecord]:

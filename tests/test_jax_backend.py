@@ -55,6 +55,18 @@ def make_pair(schema_text, rels, clock=None):
     return jx, oracle
 
 
+def make_kernel_pair(schema_text, rels):
+    """make_pair with the Leopard closure index off, so every check and
+    lookup runs the fixpoint kernel."""
+    from spicedb_kubeapi_proxy_tpu.utils.features import GATES
+    prev = GATES.enabled("LeopardIndex")
+    GATES.set("LeopardIndex", False)
+    try:
+        return make_pair(schema_text, rels)
+    finally:
+        GATES.set("LeopardIndex", prev)
+
+
 def make_clocked_pair(schema_text, rels):
     """(jx, oracle, clk): a pair on a manual clock — set clk[0] to move
     time for deterministic expiry tests."""
@@ -276,6 +288,70 @@ class TestIncrementalDeltas:
             jx.store.write(delete("namespace:ns#viewer@user:u3"))
             assert_agreement(jx, oracle, "namespace", "view",
                              users(*[f"u{i}" for i in range(12)]))
+
+    def test_insert_at_slice_width_grows_through_spare_pool(self,
+                                                           kernel_kind):
+        """36 viewers build two OR-tree nodes, 32 and 4 children wide, and
+        fill the main row.  The staged step refreshes the 4-child node at
+        width 4 only, so the 37th viewer may not take that node's dead
+        column 4: it grows through the spare pool and stays exact."""
+        rels = [f"namespace:ns#viewer@user:u{i}" for i in range(36)]
+        rels += [f"namespace:seed{i}#viewer@user:u{i}" for i in range(36, 40)]
+        jx, oracle = make_kernel_pair(GROUPS_SCHEMA, rels)
+        subjects = users(*[f"u{i}" for i in range(40)])
+        assert_agreement(jx, oracle, "namespace", "view", subjects)
+        rebuilds = jx.stats["rebuilds"]
+        if kernel_kind == "ell":
+            graph = jx._graph
+            narrow = [(lo, hi) for _, _, lo, hi, w in graph.kernel.aux_slices
+                      if w == 4]
+            assert narrow, "precondition: a built node of width 4"
+            spares = len(graph._spare_aux)
+        jx.store.write(touch("namespace:ns#viewer@user:u36"))
+        assert_agreement(jx, oracle, "namespace", "view", subjects)
+        if kernel_kind == "ell":
+            assert jx.stats["rebuilds"] == rebuilds, \
+                "the insert must grow through the spare pool, not rebuild"
+            assert len(graph._spare_aux) == spares - 1
+            dead = graph.prog.dead_index
+            for lo, hi in narrow:
+                assert (graph.host_aux[lo:hi, 4:] == dead).all()
+            jx.store.write(delete("namespace:ns#viewer@user:u2"))
+            assert_agreement(jx, oracle, "namespace", "view", subjects)
+
+    def test_crossing_spare_prefix_rejits_once(self, kernel_kind):
+        """Spare rows are taken in order and the staged step refreshes a
+        power-of-two prefix of the pool: growth past the compiled prefix
+        drops the compiled step once and bumps
+        authz_aux_spare_rejits_total; growth inside it does neither."""
+        from spicedb_kubeapi_proxy_tpu.utils import metrics as m
+        rels = [f"namespace:seed#viewer@user:u{i}" for i in range(40)]
+        roots = ["a", "b", "c", "d"]
+        rels += [f"namespace:{r}#viewer@user:u{j}"
+                 for r in roots for j in (0, 1)]
+        jx, oracle = make_kernel_pair(GROUPS_SCHEMA, rels)
+        subjects = users(*[f"u{i}" for i in range(6)])
+        assert_agreement(jx, oracle, "namespace", "view", subjects)
+        rejits = m.REGISTRY.get("authz_aux_spare_rejits_total")
+        rebuilds = jx.stats["rebuilds"]
+        # the k-th growth takes spare row k: prefixes 1, 2, 4, 4
+        for root, prefix, crossed in zip(roots, (1, 2, 4, 4),
+                                         (True, True, True, False)):
+            before = rejits.value()
+            if kernel_kind == "ell":
+                kern = jx._graph.kernel
+                compiled = dict(kern._jits)
+                assert compiled, "a query compiled the step"
+            jx.store.write(touch(f"namespace:{root}#viewer@user:u2"))
+            jx._current_graph()
+            if kernel_kind == "ell":
+                assert kern.spare_prefix == prefix
+                assert kern.spare_passes == 1
+                assert rejits.value() == before + crossed
+                assert (kern._jits == {}) == crossed
+                assert (kern._jits == compiled) != crossed
+            assert_agreement(jx, oracle, "namespace", "view", subjects)
+        assert jx.stats["rebuilds"] == rebuilds
 
     def test_new_object_id_assigns_spare_without_rebuild(self):
         """A tuple naming a brand-new object/subject id claims spare rows

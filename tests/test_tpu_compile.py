@@ -153,3 +153,64 @@ def test_sharded_lookup_compiles_with_all_gather(graph, topo,
     tables = sum(int(np.prod(t.shape)) * 4
                  for t in (kern.idx_main, kern.idx_aux))
     assert per_device < tables, (per_device, tables)
+
+
+@pytest.fixture(scope="module")
+def cell_graph():
+    """The device graph of the benchmark's `multitenant-150k`
+    configuration (its generator at its structure seed), built on the CPU
+    once: the aux OR-tree is as large as the main table there."""
+    import importlib.util
+    import json
+
+    from spicedb_kubeapi_proxy_tpu.ops.jax_endpoint import JaxEndpoint
+    from spicedb_kubeapi_proxy_tpu.spicedb.endpoints import Bootstrap
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    with open(os.path.join(bench, "configs", "multitenant-150k.json")) as f:
+        cfg = json.load(f)
+    spec = importlib.util.spec_from_file_location(
+        "multitenant_generator",
+        os.path.join(bench, "generators", cfg["generator"] + ".py"))
+    gen = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(gen)
+    with open(os.path.join(bench, "configs", cfg["schema"])) as f:
+        schema = f.read()
+    ep = JaxEndpoint.from_bootstrap(Bootstrap(
+        schema_text=schema,
+        relationships_text="\n".join(gen.build(cfg["params"], 1)
+                                     ["relationships"])))
+    ep.warm_start()
+    return ep._graph
+
+
+def test_sliced_staged_step_compiles_at_cell_shapes(cell_graph, one_chip,
+                                                    monkeypatch):
+    """The staged step that refreshes each aux slice at its width lowers
+    to plain XLA (no custom call) at the cell's shapes and its 32-lane
+    bucket, and needs no more temporary memory than the Jacobi step,
+    which refreshes the whole aux table."""
+    from spicedb_kubeapi_proxy_tpu.ops.ell import EllKernelCache
+
+    g = cell_graph
+    assert g.kernel.stages and g.kernel.aux_slices
+    off, ln = g.prog.slot_range("pod", "view")
+    rows = g.prog.state_size + g.kernel.n_aux_rows
+
+    def compile_lookup(kern):
+        run_lookup = kern._pipe_fns(1)[1].__wrapped__
+        return run_lookup.lower(
+            off, ln, _sds((32,), np.int32, one_chip),
+            _sds((rows, 1), np.uint32, one_chip),
+            *_tables(g, one_chip)).compile()
+
+    staged = compile_lookup(g.kernel)
+    assert "tpu_custom_call" not in staged.as_text()
+    monkeypatch.setenv("SPICEDB_TPU_STAGED", "0")
+    jacobi = EllKernelCache(g.prog, n_aux_rows=g.kernel.n_aux_rows,
+                            tree_depth=g.kernel.aux_passes - 1)
+    assert jacobi.stages is None
+    temp = staged.memory_analysis().temp_size_in_bytes
+    assert temp <= compile_lookup(jacobi).memory_analysis() \
+        .temp_size_in_bytes, temp
