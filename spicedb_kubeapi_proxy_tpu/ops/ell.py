@@ -79,6 +79,9 @@ K_AUX = 32
 K_CAV = 8
 
 MAX_ITERATIONS = 50  # matches embedded reference dispatch depth cap
+# Passes per sweep of one recursive stage, unrolled in the compiled step
+# (compute_stages); longer chains take further outer sweeps
+MAX_STAGE_PASSES = 16
 
 
 def batch_words(batch: int, minimum: int = 1) -> int:
@@ -349,10 +352,12 @@ def compute_stages(prog: GraphProgram) -> tuple:
 
     Returns a tuple of stage descriptors (ranges, repeat): `ranges` is a
     tuple of (lo, hi) row ranges (SCC members merge when adjacent), and
-    `repeat` is 2 when the SCC has internal edges (e.g. group#member
-    nesting) so one nesting hop resolves within the sweep instead of
-    costing an extra sweep; deeper nests still converge via the outer
-    while_loop."""
+    `repeat` is the stage's passes per sweep: 1 without internal edges,
+    else the longest chain of the SCC's internal edges in the compiled
+    edge list plus 1 (`_stage_passes`), so a whole group-nesting or
+    `parent->view` chain resolves within one sweep.  Chains that delta
+    growth lengthens, and those past MAX_STAGE_PASSES, still converge
+    via the outer while_loop, at the cost of extra sweeps."""
     # per-type contiguous range from the slot layout
     starts: dict = {}
     for (t, _slot), off in prog.slot_offsets.items():
@@ -365,14 +370,18 @@ def compute_stages(prog: GraphProgram) -> tuple:
     # type dependency edges from the compiled edge list
     b = np.asarray(bounds[:-1], np.int64)
     deps: dict = {t: set() for t in types}
-    self_dep: set = set()
+    esrc = edst = src_t = dst_t = np.zeros(0, np.int64)
     if len(prog.edge_src):
         from .graph_compile import SELF_SLOT
         live = prog.edge_dst != prog.dead_index
-        esrc = prog.edge_src[live]
+        esrc = prog.edge_src[live].astype(np.int64)
+        edst = prog.edge_dst[live].astype(np.int64)
         src_t = np.searchsorted(b, esrc, side="right") - 1
-        dst_t = np.searchsorted(b, prog.edge_dst[live], side="right") - 1
-        # a same-type edge forces a within-sweep repeat only when its
+        dst_t = np.searchsorted(b, edst, side="right") - 1
+        for s, d in set(zip(src_t.tolist(), dst_t.tolist())):
+            if s != d and 0 <= s < len(types) and 0 <= d < len(types):
+                deps[types[d]].add(types[s])  # d depends on s
+        # an edge within a stage costs a within-sweep pass only when its
         # source is a DYNAMIC slot; sources in the type's self range are
         # static query seeds and resolve in the first pass regardless
         self_lo = np.asarray(
@@ -380,16 +389,9 @@ def compute_stages(prog: GraphProgram) -> tuple:
             np.int64)
         self_hi = self_lo + np.asarray(
             [prog.num_objects.get(t, 0) for t in types], np.int64)
-        in_self = (esrc >= self_lo[src_t]) & (esrc < self_hi[src_t])
-        for s, d, st in set(zip(src_t.tolist(), dst_t.tolist(),
-                                in_self.tolist())):
-            if not (0 <= s < len(types) and 0 <= d < len(types)):
-                continue
-            if s == d:
-                if not st:
-                    self_dep.add(types[d])
-            else:
-                deps[types[d]].add(types[s])  # d depends on s
+        dynamic = ~((esrc >= self_lo[src_t]) & (esrc < self_hi[src_t]))
+        esrc, edst, src_t, dst_t = (a[dynamic] for a in (esrc, edst, src_t,
+                                                         dst_t))
     # SCC condensation (iterative Tarjan) + topological order
     index: dict = {}
     low: dict = {}
@@ -449,10 +451,57 @@ def compute_stages(prog: GraphProgram) -> tuple:
         rtuple = tuple((lo, hi) for lo, hi in merged if hi > lo)
         if not rtuple:
             continue
-        repeat = 2 if (len(comp) > 1 or any(t in self_dep for t in comp)) \
-            else 1
-        stages.append((rtuple, repeat, True))
+        members = np.asarray([types.index(t) for t in comp], np.int64)
+        inner = np.isin(src_t, members) & np.isin(dst_t, members)
+        stages.append((rtuple, _stage_passes(prog, rtuple, esrc[inner],
+                                             edst[inner]), True))
     return tuple(stages)
+
+
+def _stage_passes(prog: GraphProgram, ranges: tuple, src: np.ndarray,
+                  dst: np.ndarray) -> int:
+    """Passes per sweep that resolve every chain inside one stage: the
+    longest chain of its internal edges `src -> dst` (dynamic sources),
+    counted in edges, plus 1 for the pass that takes the chain's entry
+    from earlier stages or the query seed.
+
+    A pass gathers every row of the stage once and then recomputes the
+    stage's permission ops in order, so an edge costs a pass and a
+    permission reading its own type's slots costs none: a `parent->view`
+    hop is the edge view(parent) -> arrow(child) and then the op
+    view(child) = ... + arrow(child), one pass.  One BFS over the stage's
+    rows relaxes every edge per round; a chain has no more edges than
+    distinct sources, which bounds a cycle, and MAX_STAGE_PASSES bounds
+    the unrolled step."""
+    if not len(src):
+        return 1
+    bound = min(MAX_STAGE_PASSES - 1, len(np.unique(src)))
+    ops = [op for op in prog.perm_ops
+           if any(lo <= op.offset < hi for lo, hi in ranges)]
+    reads = [(op.offset, op.length, _expr_reads(op.expr)) for op in ops]
+    depth = np.zeros(prog.state_size, np.int32)
+    for rounds in range(bound + 1):
+        nxt = depth.copy()
+        np.maximum.at(nxt, dst, depth[src] + 1)
+        for off, n, slots in reads:
+            for r in slots:
+                np.maximum(nxt[off:off + n], nxt[r:r + n],
+                           out=nxt[off:off + n])
+        if np.array_equal(nxt, depth):
+            break
+        depth = nxt
+    return rounds + 1
+
+
+def _expr_reads(expr) -> tuple:
+    """Offsets of the slots a compiled permission expression reads."""
+    if isinstance(expr, PRead):
+        return (expr.offset,)
+    if isinstance(expr, (PUnion, PIntersect)):
+        return tuple(o for c in expr.children for o in _expr_reads(c))
+    if isinstance(expr, PExclude):
+        return _expr_reads(expr.base) + _expr_reads(expr.subtract)
+    return ()
 
 
 def make_ell_step(prog: GraphProgram, n_aux_rows: int,
@@ -891,7 +940,8 @@ class EllKernelCache:
     def _evaluate(self, n_words: int, **kw):
         """make_ell_evaluate for this cache's program and tables at the
         current stage flags and spare prefix; records the compiled
-        step's aux refresh in authz_aux_refresh_slots.
+        step's aux refresh in authz_aux_refresh_slots and its passes per
+        sweep in authz_stage_passes (the Jacobi step: one pass).
 
         Callers take `self._jits` BEFORE this reads the config and store
         into that dict: note_main_aux_ref / note_spare_row change the
@@ -900,6 +950,8 @@ class EllKernelCache:
         never in the new one (a step that skips a spare row a table
         reads is wrong, not only slower)."""
         workload.note_aux_refresh(self.aux_refresh_slots())
+        workload.note_stage_passes(
+            sum(r for _, r, _ in self.stages) if self.stages else 1)
         return make_ell_evaluate(self.prog, self.n_aux_rows, n_words,
                                  self.num_iters, planes=self.planes,
                                  aux_passes=self.aux_passes,

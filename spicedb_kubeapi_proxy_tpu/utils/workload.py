@@ -117,6 +117,10 @@ class WorkloadAccounting:
             "ell step gathers: built slices, the spare-pool prefix, or "
             "the whole table (Jacobi step)",
             labels=("kind",))
+        self._stage_passes = registry.gauge(
+            "authz_stage_passes",
+            "Stage passes one sweep of the most recently compiled ell "
+            "step runs: the sum of its stages' passes (Jacobi step: 1)")
         self._spare_rejits = registry.counter(
             "authz_aux_spare_rejits_total",
             "Compiled ell steps dropped because delta growth crossed the "
@@ -129,6 +133,11 @@ class WorkloadAccounting:
             return
         for kind, n in slots.items():
             self._aux_slots.set(float(n), kind=kind)
+
+    def note_stage_passes(self, passes: int) -> None:
+        if not enabled():
+            return
+        self._stage_passes.set(float(passes))
 
     def note_spare_rejit(self) -> None:
         if not enabled():
@@ -418,6 +427,10 @@ def note_sweep(kernel: str, verb: str, tel) -> Optional[SweepRecord]:
 
 def note_aux_refresh(slots: dict) -> None:
     WORKLOAD.note_aux_refresh(slots)
+
+
+def note_stage_passes(passes: int) -> None:
+    WORKLOAD.note_stage_passes(passes)
 
 
 def note_spare_rejit() -> None:

@@ -59,7 +59,8 @@ def _hub(n):
 
 def _nested_group_hub():
     # group:big has 20 members, one of them a nested group: the group
-    # stage has internal edges (repeat 2) and reads its own OR-tree
+    # stage has internal edges (a chain of two, core -> inner -> big, so
+    # three passes per sweep) and reads its own OR-tree
     rels = [f"group:big#member@user:u{i}" for i in range(19)]
     rels += ["group:big#member@group:inner#member",
              "group:inner#member@user:u40",
@@ -176,13 +177,16 @@ def test_sliced_staged_step_matches_oracle(case, monkeypatch):
         assert (live <= w).all() and (live > w // 2).all()
     reads = [wants for _, _, wants in kern.stages]
     if case == "repeat2_stage_reads_aux":
-        assert any(rep == 2 and wants for _, rep, wants in kern.stages)
-        # the second pass refreshes big's tree again: u40 reaches inner in
-        # the first pass and big, then ns0, in the second, so one sweep
-        # propagates and one confirms
-        with jx._lock:
-            q, _, _ = jx._encode_subjects(graph, [SubjectRef("user", "u40")])
-        assert kern.iterations(q, 1, graph.dev_main, graph.dev_aux) == 2
+        assert any(rep == 3 and wants for _, rep, wants in kern.stages)
+        # each pass refreshes big's tree again: u40 reaches inner in the
+        # first pass and big, then ns0, in the second; u41 reaches core,
+        # inner and big in three; so one sweep propagates and one confirms
+        for user in ("u40", "u41"):
+            with jx._lock:
+                q, _, _ = jx._encode_subjects(graph,
+                                              [SubjectRef("user", user)])
+            assert kern.iterations(q, 1, graph.dev_main,
+                                   graph.dev_aux) == 2, user
     if case == "stage_reads_no_aux":
         assert True in reads and False in reads
     if case == "no_aux_rows":

@@ -155,11 +155,9 @@ def test_sharded_lookup_compiles_with_all_gather(graph, topo,
     assert per_device < tables, (per_device, tables)
 
 
-@pytest.fixture(scope="module")
-def cell_graph():
-    """The device graph of the benchmark's `multitenant-150k`
-    configuration (its generator at its structure seed), built on the CPU
-    once: the aux OR-tree is as large as the main table there."""
+def _bench_graph(config: str):
+    """The device graph of a benchmark configuration (its generator at
+    its structure seed), built on the CPU."""
     import importlib.util
     import json
 
@@ -168,10 +166,10 @@ def cell_graph():
 
     bench = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "benchmark")
-    with open(os.path.join(bench, "configs", "multitenant-150k.json")) as f:
+    with open(os.path.join(bench, "configs", f"{config}.json")) as f:
         cfg = json.load(f)
     spec = importlib.util.spec_from_file_location(
-        "multitenant_generator",
+        f"{cfg['generator']}_generator",
         os.path.join(bench, "generators", cfg["generator"] + ".py"))
     gen = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(gen)
@@ -183,6 +181,19 @@ def cell_graph():
                                      ["relationships"])))
     ep.warm_start()
     return ep._graph
+
+
+@pytest.fixture(scope="module")
+def cell_graph():
+    """The device graph of the benchmark's `multitenant-150k`
+    configuration, built once: the aux OR-tree is as large as the main
+    table there."""
+    return _bench_graph("multitenant-150k")
+
+
+def _gathers(hlo: str) -> int:
+    """Gather instructions in a compiled module's text."""
+    return sum(1 for line in hlo.splitlines() if " gather(" in line)
 
 
 def test_sliced_staged_step_compiles_at_cell_shapes(cell_graph, one_chip,
@@ -214,3 +225,34 @@ def test_sliced_staged_step_compiles_at_cell_shapes(cell_graph, one_chip,
     temp = staged.memory_analysis().temp_size_in_bytes
     assert temp <= compile_lookup(jacobi).memory_analysis() \
         .temp_size_in_bytes, temp
+
+
+def test_hnc_lookup_compiles_with_nine_pass_namespace_stage(one_chip):
+    """The benchmark's `hnc-150k` graph: the depth-8 `parent->view` trees
+    give the namespace stage 9 passes per sweep, unrolled in the staged
+    step, and its 32-lane lookup still lowers to plain XLA for the chip,
+    with a few more gathers than the same step at 2 passes."""
+    from spicedb_kubeapi_proxy_tpu.ops.ell import EllKernelCache
+
+    g = _bench_graph("hnc-150k")
+    ns = g.prog.slot_offsets[("namespace", "view")]
+    assert [rep for ranges, rep, _ in g.kernel.stages
+            if any(lo <= ns < hi for lo, hi in ranges)] == [9]
+    off, ln = g.prog.slot_range("pod", "view")
+    rows = g.prog.state_size + g.kernel.n_aux_rows
+
+    def compile_lookup(kern):
+        run_lookup = kern._pipe_fns(1)[1].__wrapped__
+        return run_lookup.lower(
+            off, ln, _sds((32,), np.int32, one_chip),
+            _sds((rows, 1), np.uint32, one_chip),
+            *_tables(g, one_chip)).compile().as_text()
+
+    hlo = compile_lookup(g.kernel)
+    assert "tpu_custom_call" not in hlo
+    two = EllKernelCache(g.prog, n_aux_rows=g.kernel.n_aux_rows,
+                         tree_depth=g.kernel.aux_passes - 1,
+                         aux_slices=g.kernel.aux_slices)
+    two.stages = tuple((ranges, 2 if rep == 9 else rep, wants)
+                       for ranges, rep, wants in g.kernel.stages)
+    assert _gathers(hlo) > _gathers(compile_lookup(two)) > 0
